@@ -1,10 +1,11 @@
 """Roofline analysis from the compiled dry-run (§Roofline deliverable).
 
-Terms per (arch × shape) on the single-pod 16×16 mesh, TPU v5e constants:
+Terms per (arch × shape) on the single-pod 16×16 mesh, with the peaks of
+the target chip (``TARGET_KIND``) from ``repro.launch.mesh.PEAKS``:
 
-    compute    = HLO_FLOPs_per_device / PEAK_FLOPS_BF16
-    memory     = HLO_bytes_per_device / HBM_BW
-    collective = collective_bytes_per_device / ICI_BW
+    compute    = HLO_FLOPs_per_device / bf16_flops
+    memory     = HLO_bytes_per_device / hbm_bytes_per_s
+    collective = collective_bytes_per_device / ici_bytes_per_s_per_link
 
 METHODOLOGY — the scan-trip-count correction:  XLA's HloCostAnalysis counts a
 ``while`` body once, so a 94-layer scanned model reports ~1 layer of FLOPs.
@@ -32,9 +33,10 @@ import json
 from typing import Optional
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape, supports_shape
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 
 CHIPS = 256  # single-pod 16×16
+TARGET_KIND = "TPU v5 lite"  # TPU v5e, as jax reports its device_kind
 
 
 def active_params(cfg) -> int:
@@ -106,9 +108,10 @@ def probe_costs(arch_name: str, shape_name: str, *, multi_pod=False,
 
 
 def roofline_terms(costs: dict, cfg, shape) -> dict:
-    compute_s = costs["flops"] / PEAK_FLOPS_BF16
-    memory_s = costs["bytes"] / HBM_BW
-    coll_s = costs["collective_bytes"] / ICI_BW
+    peaks = device_peaks(TARGET_KIND)
+    compute_s = costs["flops"] / peaks["bf16_flops"]
+    memory_s = costs["bytes"] / peaks["hbm_bytes_per_s"]
+    coll_s = costs["collective_bytes"] / peaks["ici_bytes_per_s_per_link"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": coll_s}
     dom = max(terms, key=terms.get)
@@ -166,6 +169,7 @@ def main(argv=None):
     pairs = ([(args.arch, args.shape)] if not args.all else
              [(a, s) for a in sorted(ARCHS) for s in SHAPES
               if supports_shape(ARCHS[a], SHAPES[s])])
+    failures = 0
     for a, s in pairs:
         print(f"=== {a} × {s} (impl={args.step_impl}, remat={args.remat}, "
               f"ce_chunks={args.ce_chunks}) ===", flush=True)
@@ -178,16 +182,19 @@ def main(argv=None):
             import traceback
             traceback.print_exc()
             rec = {"arch": a, "shape": s, "error": str(e)}
+            failures += 1
         print(json.dumps({k: v for k, v in rec.items()
                           if k not in ("probe_1p", "probe_2p")}, indent=1))
         if args.json:
             with open(args.json, "a") as f:
                 f.write(json.dumps(rec) + "\n")
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
     import os
+    import sys
     assert os.environ.get("XLA_FLAGS"), \
         "run via: XLA_FLAGS=--xla_force_host_platform_device_count=512 " \
         "python -m benchmarks.roofline"
-    main()
+    sys.exit(main())

@@ -8,6 +8,3 @@ compiled scan engines), ``kernels`` (Pallas), ``launch``
 (drivers/dry-run), ``checkpoint``, ``data``, ``optim``, ``configs``,
 ``utils``.
 """
-from repro.utils import jax_compat
-
-jax_compat.install()

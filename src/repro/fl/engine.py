@@ -186,7 +186,7 @@ from repro.fl.server import (fedavg, make_table_evaluator, server_update_flat,
                              update_global_direction,
                              update_global_direction_flat)
 from repro.fl.simulation import (INIT_CHUNK, RunResult, _build_data,
-                                 init_gp_phase)
+                                 init_gp_phase, resolve_gp_impl)
 from repro.models import small
 from repro.obs import metrics as obs_metrics
 from repro.obs.cost import BYTES_PER_PARAM, padded_param_count
@@ -287,17 +287,6 @@ def _sync_pool_stubs() -> dict:
                 strikes=jnp.zeros((1,), jnp.int32),
                 last_sel=jnp.zeros((1,), jnp.float32),
                 sel_counts=jnp.zeros((1,), jnp.int32))
-
-
-def _resolve_gp_impl(gp_impl: str, use_gp_kernel: bool) -> str:
-    if use_gp_kernel:
-        return "kernel"
-    if gp_impl == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "stacked"
-    if gp_impl not in ("kernel", "stacked"):
-        raise ValueError(f"gp_impl must be 'auto', 'kernel' or 'stacked'; "
-                         f"got {gp_impl!r}")
-    return gp_impl
 
 
 class ScanEngine:
@@ -450,7 +439,7 @@ class ScanEngine:
                     f"shard_clients={shard_clients} but only "
                     f"{jax.device_count()} jax device(s) are visible")
         self.exp = exp
-        self.gp_impl = _resolve_gp_impl(gp_impl, use_gp_kernel)
+        self.gp_impl = resolve_gp_impl(gp_impl, use_gp_kernel)
         self.param_layout = param_layout
         self.use_ee = use_ee
         self.log_every = log_every
@@ -607,9 +596,10 @@ class ScanEngine:
                                            tiled=True)
                 if is_gpfl:
                     d_loc = flat_mod.pack_stacked(spec, d_i)
-                    # each device projects ITS clients' momenta (Eq. 3);
-                    # rows are independent dots, so local == global values
-                    gp_loc = gp_mod.gp_scores_matrix(d_loc, direction_vec)
+                    # each device projects ITS clients' momenta (Eq. 3)
+                    # with the unsharded run's score function; rows are
+                    # independent dots, so local == global values
+                    gp_loc = score_fn(d_loc, direction_vec)
                     gp = jax.lax.all_gather(gp_loc, "clients", axis=0,
                                             tiled=True)
                 else:
@@ -621,6 +611,19 @@ class ScanEngine:
                 in_specs=(repl_P, repl_P, cohort_P, cohort_P, cohort_P,
                           cohort_P),
                 out_specs=(repl_P, repl_P), check_vma=False)
+
+        def server_flat(w_mat, params_vec, direction_vec, weights):
+            return server_update_flat(
+                w_mat, params_vec, direction_vec, lr=exp.lr,
+                gamma=exp.momentum, weights=weights, use_kernel=use_kernel)
+
+        if shard > 1 and use_kernel:
+            # XLA cannot partition a Mosaic kernel: every device runs the
+            # server round on its replica of the gathered cohort, the
+            # same program as the unsharded engine
+            server_flat = jax.shard_map(server_flat, mesh=self._mesh,
+                                        in_specs=repl_P, out_specs=repl_P,
+                                        check_vma=False)
 
         def body(tabs, carry: RoundCarry, xs):
             x_tab, y_tab, sz_tab, eval_x, eval_y = tabs
@@ -767,10 +770,8 @@ class ScanEngine:
                     # one (K, Dp) pack out of the trainer, then contiguous
                     # vector passes (or the fused Pallas server kernel)
                     w_mat = flat_mod.pack_stacked(spec, w_i)
-                params, direction = server_update_flat(
-                    w_mat, carry.params, carry.direction,
-                    lr=exp.lr, gamma=exp.momentum, weights=weights,
-                    use_kernel=use_kernel)
+                params, direction = server_flat(
+                    w_mat, carry.params, carry.direction, weights)
                 acc, gl_loss = evaluate(flat_mod.unpack(spec, params),
                                         eval_x, eval_y)
             else:
@@ -1682,21 +1683,30 @@ class ScanEngine:
             return contextlib.nullcontext()
         return self.tracer.span(name, **args)
 
+    def _scan_args(self) -> tuple:
+        """The full-run scan's arguments.  params/direction are donated
+        to the scan — they are fresh copies so the cached initial state
+        survives for the next run()."""
+        (params, direction, bandit, latest_gp, fc_cov, fc_prev, key,
+         streams) = self._inputs
+        return (jax.tree.map(jnp.copy, params),
+                jax.tree.map(jnp.copy, direction),
+                bandit, latest_gp, fc_cov, fc_prev, key, streams,
+                self.store.tables(), (self.eval_x, self.eval_y))
+
+    def lower(self):
+        """The snapshot-free full-run scan lowered for this engine's
+        inputs, for inspecting the compiled program (``.compile()``,
+        ``.as_text()``) without running it."""
+        return self._compiled().lower(*self._scan_args())
+
     def _run_single(self) -> RunResult:
         """The snapshot-free fast path: one dispatch for the whole run
         (all T rounds, or — buffered — prefill + all E events)."""
-        (params, direction, bandit, latest_gp, fc_cov, fc_prev, key,
-         streams) = self._inputs
-
         t0 = time.perf_counter()
-        # params/direction are donated to the scan — pass fresh copies so
-        # the cached initial state survives for the next run()
         with self._span("scan_dispatch", rounds=int(self.events)):
-            carry, out = jax.block_until_ready(self._compiled()(
-                jax.tree.map(jnp.copy, params),
-                jax.tree.map(jnp.copy, direction),
-                bandit, latest_gp, fc_cov, fc_prev, key, streams,
-                self.store.tables(), (self.eval_x, self.eval_y)))
+            carry, out = jax.block_until_ready(
+                self._compiled()(*self._scan_args()))
         scan_wall = time.perf_counter() - t0
         self.final_carry = carry
 

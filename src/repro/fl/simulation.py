@@ -110,6 +110,21 @@ def _build_data(exp: FLExperimentConfig, seed: int,
 INIT_CHUNK = 25
 
 
+def resolve_gp_impl(gp_impl: str, use_gp_kernel: bool) -> str:
+    """The GP scoring implementation a run uses: ``use_gp_kernel`` forces
+    the Pallas kernel; ``"auto"`` picks it on a TPU backend and the jnp
+    einsum elsewhere.  Both backends resolve through here, so the host
+    loop scores with the same code as the scan on a given device."""
+    if use_gp_kernel:
+        return "kernel"
+    if gp_impl == "auto":
+        return "kernel" if jax.default_backend() == "tpu" else "stacked"
+    if gp_impl not in ("kernel", "stacked"):
+        raise ValueError(f"gp_impl must be 'auto', 'kernel' or 'stacked'; "
+                         f"got {gp_impl!r}")
+    return gp_impl
+
+
 def init_gp_phase(trainer, store, params, kinit, *, chunk: int = INIT_CHUNK):
     """Algorithm 1's initialization phase: every client trains once from
     w^0 (in chunks, bounding peak memory) → the seed global direction and
@@ -210,7 +225,8 @@ def run_python_loop(exp: FLExperimentConfig, *, log_every: int = 0,
     Args:
         exp: the experiment config.
         log_every: print progress every N rounds (0 = silent).
-        use_gp_kernel: route GP scoring through the Pallas kernel.
+        use_gp_kernel: route GP scoring through the Pallas kernel (it
+            is used on a TPU backend regardless, as by the scan engine).
         data: optional prebuilt ``(store, eval_x, eval_y)`` (a Session's
             dataset cache); ``None`` builds from ``exp``.
 
@@ -233,6 +249,7 @@ def run_python_loop(exp: FLExperimentConfig, *, log_every: int = 0,
                              warmup=exp.fedcor_warmup, d=exp.powd_d)
 
     N, K, T = store.n_clients, exp.clients_per_round, exp.rounds
+    use_kernel = resolve_gp_impl("auto", use_gp_kernel) == "kernel"
     direction = None
 
     # ---- initialization phase (Algorithm 1): every client trains once ----
@@ -269,7 +286,7 @@ def run_python_loop(exp: FLExperimentConfig, *, log_every: int = 0,
 
         # ---- GP scores vs the global momentum direction (Eq. 3) ----
         if direction is not None:
-            if use_gp_kernel:
+            if use_kernel:
                 from repro.kernels.ops import gp_projection_tree
                 gp_scores = gp_projection_tree(d_i, direction)
             else:

@@ -190,26 +190,15 @@ def run_one(arch_name: str, shape_name: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    mem = {}
-    try:
-        ma = compiled.memory_analysis()
-        for k in ("argument_size_in_bytes", "output_size_in_bytes",
-                  "temp_size_in_bytes", "generated_code_size_in_bytes",
-                  "alias_size_in_bytes"):
-            if hasattr(ma, k):
-                mem[k] = int(getattr(ma, k))
-    except Exception as e:  # noqa: BLE001
-        mem["error"] = str(e)
-
-    cost = {}
-    try:
-        ca = compiled.cost_analysis()
-        for k in ("flops", "bytes accessed", "transcendentals",
-                  "utilization operand", "optimal_seconds"):
-            if k in ca:
-                cost[k] = float(ca[k])
-    except Exception as e:  # noqa: BLE001
-        cost["error"] = str(e)
+    ma = compiled.memory_analysis()
+    mem = {k: int(getattr(ma, k)) for k in (
+        "argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "generated_code_size_in_bytes",
+        "alias_size_in_bytes")}
+    ca = compiled.cost_analysis()
+    cost = {k: float(ca[k]) for k in (
+        "flops", "bytes accessed", "transcendentals",
+        "utilization operand", "optimal_seconds") if k in ca}
 
     colls = parse_collectives(compiled.as_text())
 

@@ -51,6 +51,8 @@ from repro.fl.latency import (AggregationConfig, LatencyModel,
                               ScenarioConfig)
 from repro.fl.preselect import PreselectConfig
 from repro.fl.robust import RobustConfig
+from repro.launch.devices import check_can_spawn
+from repro.utils.compile_cache import enable_compile_cache
 
 
 class _ListPlan:
@@ -173,16 +175,22 @@ def run_plan_processes(plan, spec: ExecutionSpec, *, workers: int,
             journaling this many cells (tests the retry path).
         poll_s: parent poll interval in seconds.
 
+    The parent never runs JAX code itself. On a TPU host it refuses to
+    start when it already holds the backend, or when ``workers > 1``
+    (see :mod:`repro.launch.devices`).
+
     Returns:
         A :class:`repro.api.RunSet` in plan order, merged from the
         per-shard journals.
 
     Raises:
-        RuntimeError: a shard kept dying past ``max_restarts``, or the
-            journals are missing cells after every shard exited cleanly.
+        RuntimeError: a shard kept dying past ``max_restarts``, the
+            journals are missing cells after every shard exited cleanly,
+            or (TPU host) the workers could not each reach the chips.
     """
     if workers < 1:
         raise ValueError(f"workers must be >= 1; got {workers}")
+    check_can_spawn(workers, "run_plan_processes")
     cells = plan.cells()
     os.makedirs(journal_dir, exist_ok=True)
     payload_path = os.path.join(journal_dir, "payload.json")
@@ -289,6 +297,7 @@ def _main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--journal-dir", required=True)
     ap.add_argument("--crash-after-cells", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run_worker(args.payload, args.journal_dir, args.shard, args.workers,
                crash_after_cells=args.crash_after_cells)
 

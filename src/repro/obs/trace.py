@@ -147,27 +147,18 @@ def profiler_capture(logdir: Optional[str]):
     """Opt-in ``jax.profiler`` programmatic capture around a block.
 
     ``logdir=None`` (the default everywhere outside the bench lane) is a
-    no-op.  Capture failures (profiler unavailable on the backend, already
-    active, ...) are swallowed — profiling must never fail a run.
+    no-op.  A trace that cannot start or stop raises: a run asked to be
+    profiled does not silently come back without its trace.
     """
     if not logdir:
         yield
         return
     import jax
-    started = False
+    jax.profiler.start_trace(logdir)
     try:
-        try:
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception:
-            pass
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 def tracer_for(telemetry: str):

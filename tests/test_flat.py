@@ -70,7 +70,7 @@ def test_pack_stacked_rows_equal_per_item_pack():
 
 
 def test_float64_leaf_rejected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         tree = {"w": jnp.asarray(np.ones(4), jnp.float64)}
         with pytest.raises(TypeError, match="round-trip"):
             flat.make_flat_spec(tree)

@@ -144,3 +144,35 @@ def test_domain_stream_heterogeneous():
     toks = lm_token_stream(4, 4000, 1024, n_domains=4, seed=0)
     means = toks.mean(axis=1)
     assert np.std(means) > 30  # domains occupy different vocab slices
+
+
+@pytest.mark.parametrize("chips,holds,workers,platforms,refused", [
+    (0, True, 8, "", False),        # no TPU attached: anything goes
+    (4, False, 1, "", False),       # one child, parent off the backend
+    (4, False, 2, "", True),        # two children contend for the chips
+    (4, True, 1, "", True),         # the parent holds the chips
+    (4, True, 2, "cpu", False),     # children kept off the TPU
+])
+def test_check_can_spawn(monkeypatch, chips, holds, workers, platforms,
+                         refused):
+    """JAX children are refused on a TPU host unless each can reach it."""
+    from jax._src import hardware_utils, xla_bridge
+    from repro.launch.devices import check_can_spawn
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (chips, None))
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: holds)
+    if refused:
+        with pytest.raises(RuntimeError, match="TPU chip"):
+            check_can_spawn(workers, "test")
+    else:
+        check_can_spawn(workers, "test")
+
+
+def test_device_peaks_keyed_by_kind():
+    from repro.launch.mesh import device_peaks
+    assert device_peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("TPU v99")

@@ -31,7 +31,7 @@ def test_flat_pack_unpack_bit_exact(shapes, dtype_picks, seed):
     rng = np.random.default_rng(seed)
     dts = [jnp.float32, jnp.bfloat16, jnp.float16]
     tree = {
-        f"leaf{i}": jnp.asarray(rng.normal(size=shp) * 10 ** rng.integers(
+        f"leaf{i}": jnp.asarray(rng.normal(size=shp) * 10.0 ** rng.integers(
             -3, 4), dts[dtype_picks[i % len(dtype_picks)]])
         for i, shp in enumerate(shapes)
     }

@@ -42,6 +42,15 @@ np.testing.assert_array_equal(r1.accuracy, r2.accuracy)
 np.testing.assert_array_equal(r1.loss, r2.loss)
 np.testing.assert_array_equal(r1.coverage, r2.coverage)
 
+# the Pallas kernels inside the sharded program (interpreted here,
+# Mosaic on TPU): GP scores per device, the server round per replica
+k1 = run_experiment(exp, backend="scan", param_layout="flat",
+                    shard_clients=1, use_gp_kernel=True)
+k2 = run_experiment(exp, backend="scan", param_layout="flat",
+                    shard_clients=2, use_gp_kernel=True)
+np.testing.assert_array_equal(k1.selections, k2.selections)
+np.testing.assert_array_equal(k1.accuracy, k2.accuracy)
+
 # a baseline selector through the sharded path, pinned to the HOST loop
 exp = tiny(femnist_experiment("2spc", "random", seed=8))
 r_host = run_experiment(exp, backend="python")

@@ -21,12 +21,13 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import ARCHS, SHAPES
     from repro.dist import rules_for, init_train_state, \\
         make_gpfl_train_step, make_serve_step
+    from repro.launch.mesh import make_cpu_test_mesh
     from repro.models import build, input_specs
     from repro.models.common import logical_spec
 
     arch, kind = sys.argv[1], sys.argv[2]
     cfg = ARCHS[arch].reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_cpu_test_mesh(2, 2)
     shape = dataclasses.replace(
         SHAPES["train_4k" if kind == "train" else "decode_32k"],
         seq_len=64, global_batch=8)

@@ -44,6 +44,7 @@ import numpy as np  # noqa: E402
 from repro.api import (ExecutionSpec, RunJournal, Session,  # noqa: E402
                        cell_fingerprint)
 from repro.configs.paper import femnist_experiment  # noqa: E402
+from repro.launch.devices import check_can_spawn  # noqa: E402
 from repro.launch.sweep import _ListPlan  # noqa: E402
 
 _CHILD_CODE = """
@@ -108,8 +109,9 @@ def main(argv=None):
     cells = make_cells()
     journal = RunJournal(journal_path)
 
-    print(f"[smoke] reference run ({len(cells)} cells, in-process)")
-    reference = Session(_ListPlan(cells), ExecutionSpec(backend="scan")).run()
+    # the children need the device: this process stays off JAX until the
+    # in-process reference run, after both sweep phases have exited
+    check_can_spawn(1, "resume_smoke")
 
     print(f"[smoke] phase 1: sweep up, killing after "
           f"{args.kill_after_cells} journaled cell(s)")
@@ -150,6 +152,8 @@ def main(argv=None):
     print(f"[smoke] restart completed the remaining {rerun} cell(s); "
           f"journal integrity OK")
 
+    print(f"[smoke] reference run ({len(cells)} cells, in-process)")
+    reference = Session(_ListPlan(cells), ExecutionSpec(backend="scan")).run()
     by_key = journal.results_by_key()
     for ref in reference:
         got = by_key[cell_fingerprint(ref.config)]
