@@ -56,6 +56,11 @@ This mirrors the host-side selector contract documented in
 ``make_plain_train_step`` computation (same closure, same
 ``value_and_grad``, same MGD arithmetic), so the two are bit-identical —
 scores and bandit bookkeeping ride along as pure observers.
+
+Every step names its stages with ``jax.named_scope``: ``gpfl_score`` (the
+scoring pass), ``gpfl_gate`` (GPCB selection and the bandit's update),
+``step_grad`` (the gradient the update applies) and ``step_update`` (MGD),
+so a profiler trace puts each device op under one of them.
 """
 from __future__ import annotations
 
@@ -114,16 +119,17 @@ def _select(bandit: gpcb.BanditState, scores, k_select: int,
     order.  ``explore_unseen=False`` is the apply-step (pure-exploitation)
     mode: a step that gathers no evidence must not burn the must-explore
     rule on arms it cannot observe."""
-    u = gpcb.gpcb_values(bandit, total_rounds, rho)
-    unseen = jnp.isinf(u)
-    secondary = jnp.where(unseen, scores, u)
-    n = secondary.shape[0]
-    pos = jnp.argsort(-secondary)    # best first; stable ⇒ ties → lower index
-    rank = jnp.argsort(pos)          # 0 = best
-    unseen_level = 2.0 * n if explore_unseen else 0.0
-    vals = jnp.where(unseen, unseen_level, float(n)) - rank  # small exact ints
-    _, idx = jax.lax.top_k(vals, k_select)
-    mask = jnp.zeros(vals.shape, jnp.float32).at[idx].set(1.0)
+    with jax.named_scope("gpfl_gate"):
+        u = gpcb.gpcb_values(bandit, total_rounds, rho)
+        unseen = jnp.isinf(u)
+        secondary = jnp.where(unseen, scores, u)
+        n = secondary.shape[0]
+        pos = jnp.argsort(-secondary)    # best first; stable ⇒ ties → lower index
+        rank = jnp.argsort(pos)          # 0 = best
+        unseen_level = 2.0 * n if explore_unseen else 0.0
+        vals = jnp.where(unseen, unseen_level, float(n)) - rank  # small exact ints
+        _, idx = jax.lax.top_k(vals, k_select)
+        mask = jnp.zeros(vals.shape, jnp.float32).at[idx].set(1.0)
     return jax.lax.stop_gradient(mask), u
 
 
@@ -134,13 +140,14 @@ def _observe(bandit: gpcb.BanditState, mask, scores, loss_scalar,
     ``rewards`` lets the fused ``gp_projection_softmax`` kernel hand its
     already-normalised c̃ straight to the GPCB update (flat layout +
     ``score_kernel``); ``None`` computes the softmax here."""
-    if rewards is None:
-        rewards = gp.normalize_gp(scores)
-    mu = rewards * mask
-    mu_cal = gpcb.calibrate_reward(mu, bandit.prev_acc, bandit.prev_acc,
-                                   loss_scalar, bandit.prev_loss)
-    new_bandit = gpcb.update_state(bandit, mask, mu_cal, bandit.prev_acc,
-                                   loss_scalar)
+    with jax.named_scope("gpfl_gate"):
+        if rewards is None:
+            rewards = gp.normalize_gp(scores)
+        mu = rewards * mask
+        mu_cal = gpcb.calibrate_reward(mu, bandit.prev_acc, bandit.prev_acc,
+                                       loss_scalar, bandit.prev_loss)
+        new_bandit = gpcb.update_state(bandit, mask, mu_cal,
+                                       bandit.prev_acc, loss_scalar)
     return new_bandit, mu_cal
 
 
@@ -166,12 +173,14 @@ def make_plain_train_step(api, *, lr, gamma: float = 0.9,
         return api.loss_fn(p, b, **lkw)
 
     def step(state: TrainState, batch):
-        (loss_val, aux), grads = jax.value_and_grad(loss, has_aux=True)(
-            state.params, batch)
+        with jax.named_scope("step_grad"):
+            (loss_val, aux), grads = jax.value_and_grad(loss, has_aux=True)(
+                state.params, batch)
         grads = _constrain(grads, grad_specs)
-        new_params, mstate = mgd_update(
-            state.params, grads, MGDState(state.momentum, state.step),
-            lr=lr, gamma=gamma, weight_decay=weight_decay)
+        with jax.named_scope("step_update"):
+            new_params, mstate = mgd_update(
+                state.params, grads, MGDState(state.momentum, state.step),
+                lr=lr, gamma=gamma, weight_decay=weight_decay)
         loss32 = loss_val.astype(jnp.float32)
         new_state = TrainState(new_params, mstate.momentum, state.bandit,
                                state.step + 1, loss32)
@@ -287,8 +296,9 @@ def make_gpfl_train_step(api, *, n_groups: int, k_select: int,
     def step(state: TrainState, batch):
         params, momentum = state.params, state.momentum
         gbs = _group_batches(batch, n_groups)
-        scores, losses, auxes, stacked, rewards = score_fn(params, momentum,
-                                                           gbs)
+        with jax.named_scope("gpfl_score"):
+            scores, losses, auxes, stacked, rewards = score_fn(
+                params, momentum, gbs)
         scores = jax.lax.stop_gradient(scores)
 
         if gate:
@@ -296,37 +306,43 @@ def make_gpfl_train_step(api, *, n_groups: int, k_select: int,
                               rho)
             loss_scalar = jnp.mean(losses)
             aux = _aux_mean(auxes)
-            if isinstance(stacked, tuple):  # flat workspace: one row-combine
-                spec, gmat = stacked
-                w = mask / jnp.maximum(mask.sum(), 1.0)
-                grads = flat_mod.unpack(spec, jnp.tensordot(w, gmat, axes=1))
-            elif stacked is not None:  # tree grads impl: mask-average leaves
-                w = mask / jnp.maximum(mask.sum(), 1.0)
-                grads = jax.tree.map(
-                    lambda s: jnp.tensordot(
-                        w, s.astype(jnp.float32), axes=1).astype(s.dtype),
-                    stacked)
-            else:  # jvp impl: one backward pass of the mask-weighted loss
-                def masked_loss(p):
-                    lvec = jnp.stack([loss(p, b)[0] for b in gbs])
-                    return (mask * lvec).sum() / jnp.maximum(mask.sum(), 1.0)
+            with jax.named_scope("step_grad"):
+                if isinstance(stacked, tuple):  # flat workspace: one row-combine
+                    spec, gmat = stacked
+                    w = mask / jnp.maximum(mask.sum(), 1.0)
+                    grads = flat_mod.unpack(spec,
+                                            jnp.tensordot(w, gmat, axes=1))
+                elif stacked is not None:  # tree grads impl: mask-average leaves
+                    w = mask / jnp.maximum(mask.sum(), 1.0)
+                    grads = jax.tree.map(
+                        lambda s: jnp.tensordot(
+                            w, s.astype(jnp.float32), axes=1).astype(
+                                s.dtype),
+                        stacked)
+                else:  # jvp impl: one backward pass of the mask-weighted loss
+                    def masked_loss(p):
+                        lvec = jnp.stack([loss(p, b)[0] for b in gbs])
+                        return (mask * lvec).sum() / jnp.maximum(
+                            mask.sum(), 1.0)
 
-                grads = jax.grad(masked_loss)(params)
+                    grads = jax.grad(masked_loss)(params)
         else:
             # bit-exact plain path: the would-be selection is still computed
             # and recorded (metrics + bandit) so an ungated run logs what
             # GPFL would have picked, but the update uses the full batch.
             mask, u = _select(state.bandit, scores, k_select, total_rounds,
                               rho)
-            (loss_scalar, aux), grads = jax.value_and_grad(
-                loss, has_aux=True)(params, batch)
+            with jax.named_scope("step_grad"):
+                (loss_scalar, aux), grads = jax.value_and_grad(
+                    loss, has_aux=True)(params, batch)
 
         grads = _constrain(grads, grad_specs)
         new_bandit, mu_cal = _observe(state.bandit, mask, scores,
                                       jnp.mean(losses), rewards)
-        new_params, mstate = mgd_update(
-            params, grads, MGDState(momentum, state.step),
-            lr=lr, gamma=gamma, weight_decay=weight_decay)
+        with jax.named_scope("step_update"):
+            new_params, mstate = mgd_update(
+                params, grads, MGDState(momentum, state.step),
+                lr=lr, gamma=gamma, weight_decay=weight_decay)
         new_state = TrainState(new_params, mstate.momentum, new_bandit,
                                state.step + 1,
                                loss_scalar.astype(jnp.float32))
@@ -377,12 +393,14 @@ def make_gpfl_apply_step(api, *, n_groups: int, k_select: int,
             tot = (mask * lvec).sum() / jnp.maximum(mask.sum(), 1.0)
             return tot, (lvec, [o[1] for o in outs])
 
-        (_, (losses, auxes)), grads = jax.value_and_grad(
-            masked_loss, has_aux=True)(params)
+        with jax.named_scope("step_grad"):
+            (_, (losses, auxes)), grads = jax.value_and_grad(
+                masked_loss, has_aux=True)(params)
         grads = _constrain(grads, grad_specs)
-        new_params, mstate = mgd_update(
-            params, grads, MGDState(state.momentum, state.step),
-            lr=lr, gamma=gamma, weight_decay=weight_decay)
+        with jax.named_scope("step_update"):
+            new_params, mstate = mgd_update(
+                params, grads, MGDState(state.momentum, state.step),
+                lr=lr, gamma=gamma, weight_decay=weight_decay)
         loss_scalar = jnp.mean(losses)
         new_state = TrainState(new_params, mstate.momentum, state.bandit,
                                state.step + 1,

@@ -142,7 +142,6 @@ the batched-vs-sequential throughput (``BENCH_sweep.json``).
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -190,7 +189,7 @@ from repro.fl.simulation import (INIT_CHUNK, RunResult, _build_data,
 from repro.models import small
 from repro.obs import metrics as obs_metrics
 from repro.obs.cost import BYTES_PER_PARAM, padded_param_count
-from repro.obs.trace import SpanTracer
+from repro.obs.trace import NullTracer, SpanTracer
 from repro.utils.pytree import tree_zeros_like
 
 #: selectors the compiled engine supports — all four of the paper's
@@ -202,6 +201,9 @@ ENGINE_SELECTORS = SELECTORS
 
 #: FedCor's covariance EMA discount (matches FedCorSelector's default).
 _FEDCOR_BETA = 0.95
+
+#: the spans of an engine built without ``telemetry="trace"``
+_NULL_TRACER = NullTracer()
 
 
 class RoundCarry(NamedTuple):
@@ -523,7 +525,12 @@ class ScanEngine:
     def _build_body(self):
         """The per-round scan body, shared verbatim by the full-T scan
         and the N-round chunk scan — chunked execution therefore replays
-        the unsegmented run's math bit-identically."""
+        the unsegmented run's math bit-identically.
+
+        Each stage of the round runs under a ``jax.named_scope``
+        (``fl_select``, ``fl_gather``, ``fl_train``, ``fl_aggregate``,
+        ``fl_evaluate``, ``fl_observe``): op metadata only, which lets a
+        profiler trace put each device op under its stage."""
         exp, scn = self.exp, self.scenario
         N, K, T = self.store.n_clients, exp.clients_per_round, exp.rounds
         W = max(exp.fedcor_warmup, 2)   # FedCor needs 2 loss probes to rank
@@ -595,13 +602,14 @@ class ScanEngine:
                 w_mat = jax.lax.all_gather(w_loc, "clients", axis=0,
                                            tiled=True)
                 if is_gpfl:
-                    d_loc = flat_mod.pack_stacked(spec, d_i)
                     # each device projects ITS clients' momenta (Eq. 3)
                     # with the unsharded run's score function; rows are
                     # independent dots, so local == global values
-                    gp_loc = score_fn(d_loc, direction_vec)
-                    gp = jax.lax.all_gather(gp_loc, "clients", axis=0,
-                                            tiled=True)
+                    with jax.named_scope("fl_observe"):
+                        d_loc = flat_mod.pack_stacked(spec, d_i)
+                        gp_loc = score_fn(d_loc, direction_vec)
+                        gp = jax.lax.all_gather(gp_loc, "clients", axis=0,
+                                                tiled=True)
                 else:
                     gp = jnp.zeros((K,), jnp.float32)
                 return w_mat, gp
@@ -628,250 +636,258 @@ class ScanEngine:
         def body(tabs, carry: RoundCarry, xs):
             x_tab, y_tab, sz_tab, eval_x, eval_y = tabs
             t, jitter, sel_ids, cand_ids, avail, lat, flt, pjit = xs
-            key, kt = jax.random.split(carry.key)
-            avail_arg = avail if has_avail else None
-            if quarantine > 0 and (is_gpfl or is_fedcor):
-                # quarantine repeat offenders out of in-scan selection
-                # via the avail plumbing — but never starve the cohort:
-                # if masking leaves fewer than K candidates, fall back
-                # to the unquarantined base set for this round
-                base = avail if has_avail else jnp.ones((N,), bool)
-                cand = base & (carry.strikes < quarantine)
-                enough = jnp.sum(cand.astype(jnp.int32)) >= K
-                avail_arg = jnp.where(enough, cand, base)
-            params_in = flat_mod.unpack(spec, carry.params) if is_flat \
-                else carry.params
+            with jax.named_scope("fl_select"):
+                key, kt = jax.random.split(carry.key)
+                avail_arg = avail if has_avail else None
+                if quarantine > 0 and (is_gpfl or is_fedcor):
+                    # quarantine repeat offenders out of in-scan selection
+                    # via the avail plumbing — but never starve the cohort:
+                    # if masking leaves fewer than K candidates, fall back
+                    # to the unquarantined base set for this round
+                    base = avail if has_avail else jnp.ones((N,), bool)
+                    cand = base & (carry.strikes < quarantine)
+                    enough = jnp.sum(cand.astype(jnp.int32)) >= K
+                    avail_arg = jnp.where(enough, cand, base)
+                params_in = flat_mod.unpack(spec, carry.params) if is_flat \
+                    else carry.params
 
-            # ---- tier-1 pre-selection: narrow N to the candidate pool ----
-            pool_ids_r = pool_mask = sel_avail = None
-            if pooled:
-                u = gpcb.gpcb_values(carry.bandit, T, exp.rho)
-                gp_term = gp_mod.normalize_gp(carry.latest_gp)
-                if pool_scores_sharded is not None:
-                    # sharded runs never carry an avail mask (the robust
-                    # and availability axes both reject shard_clients>1)
-                    pscores = pool_scores_sharded(
-                        u, gp_term, carry.last_sel, pjit, t)
-                else:
-                    pscores = gpcb.pool_scores(
-                        u, gp_term, carry.last_sel, t, T, pjit,
-                        avail=avail_arg)
-                pool_ids_r = gpcb.pool_topk(pscores, P)
-                pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
-                base_m = avail_arg if avail_arg is not None \
-                    else jnp.ones((N,), bool)
-                sel_avail = compose_selection_mask(pool_mask, base_m, K)
-
-            # ---- selection (fixed-shape, pure jnp) ----
-            all_losses = None
-            if is_gpfl:
-                scores = gpcb.selection_scores(
-                    carry.bandit, carry.latest_gp, jitter, t, T,
-                    rho=exp.rho, use_ee=use_ee,
-                    avail=sel_avail if pooled else avail_arg)
-                ids = jnp.argsort(-scores)[:K]
-            elif is_random:
-                # pooled: the stream carries RANKS into the (sorted) pool
-                # — at P = N the pool is arange(N), so take(pool, ranks)
-                # replays random_id_stream's draws bit-identically
-                ids = jnp.take(pool_ids_r, sel_ids) if pooled else sel_ids
-            elif is_powd:
-                cx, cy, csz = ClientStore.gather_tables(
-                    x_tab, y_tab, sz_tab, cand_ids)
-                closs = loss_eval(params_in, cx, cy, csz)
+                # ---- tier-1 pre-selection: narrow N to the candidate pool
+                pool_ids_r = pool_mask = sel_avail = None
                 if pooled:
-                    # restrict the host-drawn candidates to the pool
-                    # in-scan (the candidate stream itself must stay
-                    # untouched for host-RNG parity); out-of-pool
-                    # candidates rank -inf unless that would starve the
-                    # top-K
-                    in_pool = jnp.take(pool_mask, cand_ids)
-                    enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= K
-                    closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
-                                      closs)
-                ids = jnp.take(cand_ids, jnp.argsort(-closs)[:K])
-            else:  # fedcor
-                all_losses = loss_eval(params_in, x_tab, y_tab, sz_tab)
-                warm = (lambda: jnp.take(pool_ids_r, sel_ids)) if pooled \
-                    else (lambda: sel_ids)
-                ids = jax.lax.cond(
-                    t < W,
-                    warm,
-                    lambda: fedcor_greedy(
-                        carry.fc_cov, K,
-                        avail=sel_avail if pooled else avail_arg))
-            ids = ids.astype(jnp.int32)
+                    u = gpcb.gpcb_values(carry.bandit, T, exp.rho)
+                    gp_term = gp_mod.normalize_gp(carry.latest_gp)
+                    if pool_scores_sharded is not None:
+                        # sharded runs never carry an avail mask (the robust
+                        # and availability axes both reject shard_clients>1)
+                        pscores = pool_scores_sharded(
+                            u, gp_term, carry.last_sel, pjit, t)
+                    else:
+                        pscores = gpcb.pool_scores(
+                            u, gp_term, carry.last_sel, t, T, pjit,
+                            avail=avail_arg)
+                    pool_ids_r = gpcb.pool_topk(pscores, P)
+                    pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
+                    base_m = avail_arg if avail_arg is not None \
+                        else jnp.ones((N,), bool)
+                    sel_avail = compose_selection_mask(pool_mask, base_m, K)
+
+                # ---- selection (fixed-shape, pure jnp) ----
+                all_losses = None
+                if is_gpfl:
+                    scores = gpcb.selection_scores(
+                        carry.bandit, carry.latest_gp, jitter, t, T,
+                        rho=exp.rho, use_ee=use_ee,
+                        avail=sel_avail if pooled else avail_arg)
+                    ids = jnp.argsort(-scores)[:K]
+                elif is_random:
+                    # pooled: the stream carries RANKS into the (sorted) pool
+                    # — at P = N the pool is arange(N), so take(pool, ranks)
+                    # replays random_id_stream's draws bit-identically
+                    ids = jnp.take(pool_ids_r, sel_ids) if pooled else sel_ids
+                elif is_powd:
+                    cx, cy, csz = ClientStore.gather_tables(
+                        x_tab, y_tab, sz_tab, cand_ids)
+                    closs = loss_eval(params_in, cx, cy, csz)
+                    if pooled:
+                        # restrict the host-drawn candidates to the pool
+                        # in-scan (the candidate stream itself must stay
+                        # untouched for host-RNG parity); out-of-pool
+                        # candidates rank -inf unless that would starve the
+                        # top-K
+                        in_pool = jnp.take(pool_mask, cand_ids)
+                        enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= K
+                        closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
+                                          closs)
+                    ids = jnp.take(cand_ids, jnp.argsort(-closs)[:K])
+                else:  # fedcor
+                    all_losses = loss_eval(params_in, x_tab, y_tab, sz_tab)
+                    warm = (lambda: jnp.take(pool_ids_r, sel_ids)) if pooled \
+                        else (lambda: sel_ids)
+                    ids = jax.lax.cond(
+                        t < W,
+                        warm,
+                        lambda: fedcor_greedy(
+                            carry.fc_cov, K,
+                            avail=sel_avail if pooled else avail_arg))
+                ids = ids.astype(jnp.int32)
 
             # ---- cohort local training (vmapped; sharded when asked) ----
-            x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab, ids)
-            rngs = jax.random.split(kt, K)
-            w_mat = w_i = d_i = gp_sharded = None
-            if shard > 1:
-                w_mat, gp_sharded = cohort_sharded(
-                    carry.params, carry.direction, x, y, sizes,
-                    jax.random.key_data(rngs))
-            else:
-                w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
+            with jax.named_scope("fl_gather"):
+                x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab,
+                                                        ids)
+                rngs = jax.random.split(kt, K)
+            with jax.named_scope("fl_train"):
+                w_mat = w_i = d_i = gp_sharded = None
+                if shard > 1:
+                    w_mat, gp_sharded = cohort_sharded(
+                        carry.params, carry.direction, x, y, sizes,
+                        jax.random.key_data(rngs))
+                else:
+                    w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
 
-            # ---- adversarial corruption of the cohort's updates ----
-            delivered = None
-            if has_faults:
-                # corrupt the trainer's TREE output before any packing,
-                # so one corruption path serves both layouts (the robust
-                # constraint rejects shard_clients > 1, so w_i is live)
-                hit = jnp.take(flt, ids)
-                fkey = jax.random.fold_in(kt, 0x0F17)
-                w_i, d_i, delivered = corrupt_cohort(
-                    faults, fkey, hit, w_i, d_i, params_in)
+                # ---- adversarial corruption of the cohort's updates ----
+                delivered = None
+                if has_faults:
+                    # corrupt the trainer's TREE output before any packing,
+                    # so one corruption path serves both layouts (the robust
+                    # constraint rejects shard_clients > 1, so w_i is live)
+                    hit = jnp.take(flt, ids)
+                    fkey = jax.random.fold_in(kt, 0x0F17)
+                    w_i, d_i, delivered = corrupt_cohort(
+                        faults, fkey, hit, w_i, d_i, params_in)
 
             # ---- straggler deadlines: late clients miss aggregation ----
-            if has_lat:
-                done = jnp.take(lat, ids) <= deadline
-                cnt = jnp.sum(done.astype(jnp.float32))
-                # nobody made it → fall back to plain FedAvg over the
-                # cohort (the server cannot skip a round in fixed shapes)
-                weights = jnp.where(cnt > 0,
-                                    done.astype(jnp.float32)
-                                    / jnp.maximum(cnt, 1.0),
-                                    jnp.full((K,), 1.0 / K, jnp.float32))
-            else:
-                done, weights = None, None
-
-            # ---- server update + evaluation ----
-            valid = None
-            if robust_active:
-                # the non-finite screen: diverged/poisoned rows are
-                # masked out of aggregation entirely; dropped-out and
-                # straggler rows fold into the same validity mask (a
-                # masked mean over valid rows ≡ the legacy done-weighted
-                # FedAvg; an all-invalid round keeps params unchanged)
-                valid = finite_rows(w_i)
-                if delivered is not None:
-                    valid = valid & delivered
-                if done is not None:
-                    valid = valid & done
-                cohort = flat_mod.pack_stacked(spec, w_i) if is_flat \
-                    else w_i
-                params = robust_aggregate(robust, cohort, carry.params,
-                                          valid)
-                if is_flat:
-                    direction = update_global_direction_flat(
-                        carry.direction, carry.params, params, exp.lr,
-                        exp.momentum)
-                    acc, gl_loss = evaluate(flat_mod.unpack(spec, params),
-                                            eval_x, eval_y)
+            with jax.named_scope("fl_aggregate"):
+                if has_lat:
+                    done = jnp.take(lat, ids) <= deadline
+                    cnt = jnp.sum(done.astype(jnp.float32))
+                    # nobody made it → fall back to plain FedAvg over the
+                    # cohort (the server cannot skip a round in fixed shapes)
+                    weights = jnp.where(cnt > 0,
+                                        done.astype(jnp.float32)
+                                        / jnp.maximum(cnt, 1.0),
+                                        jnp.full((K,), 1.0 / K, jnp.float32))
                 else:
+                    done, weights = None, None
+
+                # ---- server update ----
+                valid = None
+                if robust_active:
+                    # the non-finite screen: diverged/poisoned rows are
+                    # masked out of aggregation entirely; dropped-out and
+                    # straggler rows fold into the same validity mask (a
+                    # masked mean over valid rows ≡ the legacy done-weighted
+                    # FedAvg; an all-invalid round keeps params unchanged)
+                    valid = finite_rows(w_i)
+                    if delivered is not None:
+                        valid = valid & delivered
+                    if done is not None:
+                        valid = valid & done
+                    cohort = flat_mod.pack_stacked(spec, w_i) if is_flat \
+                        else w_i
+                    params = robust_aggregate(robust, cohort, carry.params,
+                                              valid)
+                    if is_flat:
+                        direction = update_global_direction_flat(
+                            carry.direction, carry.params, params, exp.lr,
+                            exp.momentum)
+                    else:
+                        direction = update_global_direction(
+                            carry.direction, carry.params, params, exp.lr,
+                            exp.momentum)
+                elif is_flat:
+                    if w_mat is None:
+                        # one (K, Dp) pack out of the trainer, then contiguous
+                        # vector passes (or the fused Pallas server kernel)
+                        w_mat = flat_mod.pack_stacked(spec, w_i)
+                    params, direction = server_flat(
+                        w_mat, carry.params, carry.direction, weights)
+                else:
+                    params = fedavg(w_i, weights)
                     direction = update_global_direction(
                         carry.direction, carry.params, params, exp.lr,
                         exp.momentum)
-                    acc, gl_loss = evaluate(params, eval_x, eval_y)
-            elif is_flat:
-                if w_mat is None:
-                    # one (K, Dp) pack out of the trainer, then contiguous
-                    # vector passes (or the fused Pallas server kernel)
-                    w_mat = flat_mod.pack_stacked(spec, w_i)
-                params, direction = server_flat(
-                    w_mat, carry.params, carry.direction, weights)
-                acc, gl_loss = evaluate(flat_mod.unpack(spec, params),
-                                        eval_x, eval_y)
-            else:
-                params = fedavg(w_i, weights)
-                direction = update_global_direction(
-                    carry.direction, carry.params, params, exp.lr,
-                    exp.momentum)
-                acc, gl_loss = evaluate(params, eval_x, eval_y)
+
+            # ---- evaluation of the new global model ----
+            with jax.named_scope("fl_evaluate"):
+                acc, gl_loss = evaluate(
+                    flat_mod.unpack(spec, params) if is_flat else params,
+                    eval_x, eval_y)
 
             # ---- per-selector feedback state ----
-            if is_gpfl:
-                if gp_sharded is not None:
-                    gp_scores = gp_sharded
+            with jax.named_scope("fl_observe"):
+                if is_gpfl:
+                    if gp_sharded is not None:
+                        gp_scores = gp_sharded
+                    else:
+                        grads_in = flat_mod.pack_stacked(spec, d_i) \
+                            if is_flat else d_i
+                        gp_scores = score_fn(grads_in, carry.direction)
+                    # robust path: corrupted rows must not write the bandit
+                    # (their Eq. 3 scores are poisoned) — mask them out like
+                    # straggler-dropped clients, plus any non-finite score
+                    vm = valid & jnp.isfinite(gp_scores) if robust_active \
+                        else done
+                    bandit, latest_gp = gpcb.observe(
+                        carry.bandit, carry.latest_gp, ids, gp_scores, acc,
+                        gl_loss, valid_mask=vm)
                 else:
-                    grads_in = flat_mod.pack_stacked(spec, d_i) if is_flat \
-                        else d_i
-                    gp_scores = score_fn(grads_in, carry.direction)
-                # robust path: corrupted rows must not write the bandit
-                # (their Eq. 3 scores are poisoned) — mask them out like
-                # straggler-dropped clients, plus any non-finite score
-                vm = valid & jnp.isfinite(gp_scores) if robust_active \
-                    else done
-                bandit, latest_gp = gpcb.observe(
-                    carry.bandit, carry.latest_gp, ids, gp_scores, acc,
-                    gl_loss, valid_mask=vm)
-            else:
-                bandit, latest_gp = carry.bandit, carry.latest_gp
+                    bandit, latest_gp = carry.bandit, carry.latest_gp
 
-            if is_fedcor:
-                fc_cov = jax.lax.cond(
-                    t >= 1,
-                    lambda: fedcor_cov_update(carry.fc_cov, carry.fc_prev,
-                                              all_losses, beta=_FEDCOR_BETA),
-                    lambda: carry.fc_cov)
-                fc_prev = all_losses
-            else:
-                fc_cov, fc_prev = carry.fc_cov, carry.fc_prev
-
-            seen = carry.seen.at[ids].set(True)
-            cov = jnp.mean(seen.astype(jnp.float32))
-
-            if log_every:
-                fmt = (f"[{exp.name}/scan] round {{r}}/{T} acc={{a:.4f}} "
-                       "loss={l:.4f} cov={c:.2f}")
-                jax.lax.cond(
-                    (t + 1) % log_every == 0,
-                    lambda op: jax.debug.print(fmt, r=op[0] + 1, a=op[1],
-                                               l=op[2], c=op[3]),
-                    lambda op: None,
-                    (t, acc, gl_loss, cov))
-
-            out = {"ids": ids, "acc": acc, "loss": gl_loss, "coverage": cov}
-            rep = dict(params=params, direction=direction, bandit=bandit,
-                       latest_gp=latest_gp, seen=seen, key=key,
-                       fc_cov=fc_cov, fc_prev=fc_prev)
-            if quarantine > 0:
-                # a strike = a DETECTABLY corrupt update that arrived
-                # (dropout rows never arrive, so they cannot offend)
-                offense = ~finite_rows(w_i)
-                if delivered is not None:
-                    offense = offense & delivered
-                rep["strikes"] = carry.strikes.at[ids].add(
-                    offense.astype(jnp.int32))
-            if pooled:
-                rep["last_sel"] = carry.last_sel.at[ids].set(
-                    jnp.asarray(t, jnp.float32))
-                out["pool"] = pool_ids_r
-            if counters:
-                # the telemetry axis: per-round metric counters as extra
-                # scan outs — everything here reuses values the body
-                # already materialised, and NONE of it is traced when the
-                # gate is off (the off-mode bit-parity contract)
-                rep["sel_counts"] = carry.sel_counts.at[ids].add(1)
-                if robust_active:
-                    n_del = jnp.sum(valid.astype(jnp.float32))
-                elif has_lat:
-                    n_del = jnp.sum(done.astype(jnp.float32))
+                if is_fedcor:
+                    fc_cov = jax.lax.cond(
+                        t >= 1,
+                        lambda: fedcor_cov_update(
+                            carry.fc_cov, carry.fc_prev, all_losses,
+                            beta=_FEDCOR_BETA),
+                        lambda: carry.fc_cov)
+                    fc_prev = all_losses
                 else:
-                    n_del = jnp.asarray(float(K), jnp.float32)
-                if is_gpfl and d_i is not None:
-                    align = obs_metrics.alignment_cosine(
-                        gp_scores, obs_metrics.cohort_sq_norms(d_i))
-                else:
-                    align = jnp.zeros((), jnp.float32)
-                out.update({
-                    "m_participants": jnp.asarray(float(K), jnp.float32),
-                    "m_delivered": n_del,
-                    "m_selection_entropy":
-                        obs_metrics.selection_entropy(rep["sel_counts"]),
-                    "m_gp_alignment": align,
-                    "m_screened": (K - n_del) if robust_active
-                        else jnp.zeros((), jnp.float32),
-                    "m_quarantined": jnp.sum(
-                        (rep["strikes"] >= quarantine)
-                        .astype(jnp.float32)) if quarantine > 0
-                        else jnp.zeros((), jnp.float32),
-                    "m_pool_recall": jnp.mean(
-                        jnp.take(pool_mask, ids).astype(jnp.float32))
-                        if pooled else jnp.ones((), jnp.float32),
-                })
+                    fc_cov, fc_prev = carry.fc_cov, carry.fc_prev
+
+                seen = carry.seen.at[ids].set(True)
+                cov = jnp.mean(seen.astype(jnp.float32))
+
+                if log_every:
+                    fmt = (f"[{exp.name}/scan] round {{r}}/{T} acc={{a:.4f}} "
+                           "loss={l:.4f} cov={c:.2f}")
+                    jax.lax.cond(
+                        (t + 1) % log_every == 0,
+                        lambda op: jax.debug.print(fmt, r=op[0] + 1, a=op[1],
+                                                   l=op[2], c=op[3]),
+                        lambda op: None,
+                        (t, acc, gl_loss, cov))
+
+                out = {"ids": ids, "acc": acc, "loss": gl_loss,
+                       "coverage": cov}
+                rep = dict(params=params, direction=direction, bandit=bandit,
+                           latest_gp=latest_gp, seen=seen, key=key,
+                           fc_cov=fc_cov, fc_prev=fc_prev)
+                if quarantine > 0:
+                    # a strike = a DETECTABLY corrupt update that arrived
+                    # (dropout rows never arrive, so they cannot offend)
+                    offense = ~finite_rows(w_i)
+                    if delivered is not None:
+                        offense = offense & delivered
+                    rep["strikes"] = carry.strikes.at[ids].add(
+                        offense.astype(jnp.int32))
+                if pooled:
+                    rep["last_sel"] = carry.last_sel.at[ids].set(
+                        jnp.asarray(t, jnp.float32))
+                    out["pool"] = pool_ids_r
+                if counters:
+                    # the telemetry axis: per-round metric counters as extra
+                    # scan outs — everything here reuses values the body
+                    # already materialised, and NONE of it is traced when the
+                    # gate is off (the off-mode bit-parity contract)
+                    rep["sel_counts"] = carry.sel_counts.at[ids].add(1)
+                    if robust_active:
+                        n_del = jnp.sum(valid.astype(jnp.float32))
+                    elif has_lat:
+                        n_del = jnp.sum(done.astype(jnp.float32))
+                    else:
+                        n_del = jnp.asarray(float(K), jnp.float32)
+                    if is_gpfl and d_i is not None:
+                        align = obs_metrics.alignment_cosine(
+                            gp_scores, obs_metrics.cohort_sq_norms(d_i))
+                    else:
+                        align = jnp.zeros((), jnp.float32)
+                    out.update({
+                        "m_participants": jnp.asarray(float(K), jnp.float32),
+                        "m_delivered": n_del,
+                        "m_selection_entropy":
+                            obs_metrics.selection_entropy(rep["sel_counts"]),
+                        "m_gp_alignment": align,
+                        "m_screened": (K - n_del) if robust_active
+                            else jnp.zeros((), jnp.float32),
+                        "m_quarantined": jnp.sum(
+                            (rep["strikes"] >= quarantine)
+                            .astype(jnp.float32)) if quarantine > 0
+                            else jnp.zeros((), jnp.float32),
+                        "m_pool_recall": jnp.mean(
+                            jnp.take(pool_mask, ids).astype(jnp.float32))
+                            if pooled else jnp.ones((), jnp.float32),
+                    })
             return carry._replace(**rep), out
 
         return body
@@ -932,66 +948,70 @@ class ScanEngine:
                     key, streams, tables):
             jitter, sel_ids, cand_ids, avail, lat, flt, pjit = streams
             x_tab, y_tab, sz_tab = tables
-            key, kt = jax.random.split(key)
-            avail_arg = avail[0] if has_avail else None
-            params_in = flat_mod.unpack(spec, params) if is_flat else params
+            with jax.named_scope("fl_select"):
+                key, kt = jax.random.split(key)
+                avail_arg = avail[0] if has_avail else None
+                params_in = flat_mod.unpack(spec, params) if is_flat \
+                    else params
 
-            # tier-1 pool at dispatch slot 0 (pool jitter row 0 — the
-            # event body consumes row t = e + 1, the stream discipline)
-            last_sel = jnp.full((N,), -1.0, jnp.float32) if pooled \
-                else jnp.zeros((1,), jnp.float32)
-            pool_ids_r = pool_mask = sel_avail = None
-            if pooled:
-                u = gpcb.gpcb_values(bandit, E, exp.rho)
-                gp_term = gp_mod.normalize_gp(latest_gp)
-                pscores = gpcb.pool_scores(u, gp_term, last_sel, 0, E,
-                                           pjit[0], avail=avail_arg)
-                pool_ids_r = gpcb.pool_topk(pscores, P)
-                pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
-                base_m = avail_arg if avail_arg is not None \
-                    else jnp.ones((N,), bool)
-                sel_avail = compose_selection_mask(pool_mask, base_m, K)
-
-            if is_gpfl:
-                scores = gpcb.selection_scores(
-                    bandit, latest_gp, jitter[0], 0, E,
-                    rho=exp.rho, use_ee=use_ee,
-                    avail=sel_avail if pooled else avail_arg)
-                ids = jnp.argsort(-scores)[:K]
-            elif is_random:
-                ids = jnp.take(pool_ids_r, sel_ids[0]) if pooled \
-                    else sel_ids[0]
-            elif is_powd:
-                cx, cy, csz = ClientStore.gather_tables(
-                    x_tab, y_tab, sz_tab, cand_ids[0])
-                closs = loss_eval(params_in, cx, cy, csz)
+                # tier-1 pool at dispatch slot 0 (pool jitter row 0 — the
+                # event body consumes row t = e + 1, the stream discipline)
+                last_sel = jnp.full((N,), -1.0, jnp.float32) if pooled \
+                    else jnp.zeros((1,), jnp.float32)
+                pool_ids_r = pool_mask = sel_avail = None
                 if pooled:
-                    in_pool = jnp.take(pool_mask, cand_ids[0])
-                    enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= K
-                    closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
-                                      closs)
-                ids = jnp.take(cand_ids[0], jnp.argsort(-closs)[:K])
-            else:  # fedcor: round 0 is always warm-up (W >= 2), but the
-                # all-client probe still runs and seeds fc_prev
-                fc_prev = loss_eval(params_in, x_tab, y_tab, sz_tab)
-                ids = jnp.take(pool_ids_r, sel_ids[0]) if pooled \
-                    else sel_ids[0]
-            ids = ids.astype(jnp.int32)
-            if pooled:
-                last_sel = last_sel.at[ids].set(0.0)
+                    u = gpcb.gpcb_values(bandit, E, exp.rho)
+                    gp_term = gp_mod.normalize_gp(latest_gp)
+                    pscores = gpcb.pool_scores(u, gp_term, last_sel, 0, E,
+                                               pjit[0], avail=avail_arg)
+                    pool_ids_r = gpcb.pool_topk(pscores, P)
+                    pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
+                    base_m = avail_arg if avail_arg is not None \
+                        else jnp.ones((N,), bool)
+                    sel_avail = compose_selection_mask(pool_mask, base_m, K)
 
-            x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab,
-                                                    ids)
-            rngs = jax.random.split(kt, K)
-            w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
-            pool_ok = jnp.ones((K,), bool)
-            if has_faults:
-                # stream row 0 belongs to the prefill (event e consumes
-                # row e+1) — same row discipline as the selector streams
-                hit = jnp.take(flt[0], ids)
-                fkey = jax.random.fold_in(kt, 0x0F17)
-                w_i, d_i, pool_ok = corrupt_cohort(
-                    faults, fkey, hit, w_i, d_i, params_in)
+                if is_gpfl:
+                    scores = gpcb.selection_scores(
+                        bandit, latest_gp, jitter[0], 0, E,
+                        rho=exp.rho, use_ee=use_ee,
+                        avail=sel_avail if pooled else avail_arg)
+                    ids = jnp.argsort(-scores)[:K]
+                elif is_random:
+                    ids = jnp.take(pool_ids_r, sel_ids[0]) if pooled \
+                        else sel_ids[0]
+                elif is_powd:
+                    cx, cy, csz = ClientStore.gather_tables(
+                        x_tab, y_tab, sz_tab, cand_ids[0])
+                    closs = loss_eval(params_in, cx, cy, csz)
+                    if pooled:
+                        in_pool = jnp.take(pool_mask, cand_ids[0])
+                        enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= K
+                        closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
+                                          closs)
+                    ids = jnp.take(cand_ids[0], jnp.argsort(-closs)[:K])
+                else:  # fedcor: round 0 is always warm-up (W >= 2), but the
+                    # all-client probe still runs and seeds fc_prev
+                    fc_prev = loss_eval(params_in, x_tab, y_tab, sz_tab)
+                    ids = jnp.take(pool_ids_r, sel_ids[0]) if pooled \
+                        else sel_ids[0]
+                ids = ids.astype(jnp.int32)
+                if pooled:
+                    last_sel = last_sel.at[ids].set(0.0)
+
+            with jax.named_scope("fl_gather"):
+                x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab,
+                                                        ids)
+                rngs = jax.random.split(kt, K)
+            with jax.named_scope("fl_train"):
+                w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
+                pool_ok = jnp.ones((K,), bool)
+                if has_faults:
+                    # stream row 0 belongs to the prefill (event e consumes
+                    # row e+1) — same row discipline as the selector streams
+                    hit = jnp.take(flt[0], ids)
+                    fkey = jax.random.fold_in(kt, 0x0F17)
+                    w_i, d_i, pool_ok = corrupt_cohort(
+                        faults, fkey, hit, w_i, d_i, params_in)
             strikes = jnp.zeros((N,) if quarantine > 0 else (1,),
                                 jnp.int32)
             # the prefill is dispatch slot 0: its cohort seeds the
@@ -1019,7 +1039,8 @@ class ScanEngine:
         bandit, then select + train the M replacement clients against
         the just-aggregated model.  Event e dispatches cohort slot
         t = e + 1, consuming stream row t and one key split — the sync
-        body's round-t discipline."""
+        body's round-t discipline.  The stages carry the sync body's
+        ``fl_*`` scopes."""
         exp, scn = self.exp, self.scenario
         N, K = self.store.n_clients, exp.clients_per_round
         M, E = self.buffer_m, self.events
@@ -1059,253 +1080,259 @@ class ScanEngine:
         def body(tabs, carry: RoundCarry, xs):
             x_tab, y_tab, sz_tab, eval_x, eval_y = tabs
             e, jitter, sel_row, cand_row, avail, lat, flt, pjit = xs
-            key, kt = jax.random.split(carry.key)
             t = e + 1   # the dispatch slot: sync round t's stream row
             avail_arg = avail if has_avail else None
 
             # ---- flush the M earliest-completing in-flight updates ----
-            # stable argsort: equal ready times keep pool (= dispatch)
-            # order, which the zero-latency parity contract relies on
-            order = jnp.argsort(carry.pool_ready, stable=True)
-            flush, keep = order[:M], order[M:]
-            f_ids = jnp.take(carry.pool_ids, flush)
-            # dispatch at event j stamps version j+1, so a slot flushed
-            # at the very next event has staleness 0
-            staleness = e - jnp.take(carry.pool_ver, flush)
-            lam = jnp.power(discount, staleness.astype(jnp.float32))
-            all_fresh = jnp.all(staleness == 0)
-            w_flush = take(carry.pool_w, flush)
-            d_flush = take(carry.pool_d, flush)
-            # the server "wakes up" when the M-th update lands; kept
-            # slots complete later and new dispatches start from here,
-            # so the clock is monotone
-            clock = jnp.take(carry.pool_ready, order[M - 1])
+            with jax.named_scope("fl_aggregate"):
+                # stable argsort: equal ready times keep pool (= dispatch)
+                # order, which the zero-latency parity contract relies on
+                order = jnp.argsort(carry.pool_ready, stable=True)
+                flush, keep = order[:M], order[M:]
+                f_ids = jnp.take(carry.pool_ids, flush)
+                # dispatch at event j stamps version j+1, so a slot flushed
+                # at the very next event has staleness 0
+                staleness = e - jnp.take(carry.pool_ver, flush)
+                lam = jnp.power(discount, staleness.astype(jnp.float32))
+                all_fresh = jnp.all(staleness == 0)
+                w_flush = take(carry.pool_w, flush)
+                d_flush = take(carry.pool_d, flush)
+                # the server "wakes up" when the M-th update lands; kept
+                # slots complete later and new dispatches start from here,
+                # so the clock is monotone
+                clock = jnp.take(carry.pool_ready, order[M - 1])
 
-            valid = None
-            if robust_active:
-                # the flush's validity mask: undelivered (dropout) slots
-                # plus non-finite rows are screened out of aggregation
-                valid = jnp.take(carry.pool_ok, flush) \
-                    & finite_rows(w_flush)
-                params = robust_aggregate(robust, w_flush, carry.params,
-                                          valid, weights=lam)
-                if is_flat:
-                    direction = update_global_direction_flat(
-                        carry.direction, carry.params, params, exp.lr,
-                        exp.momentum)
-                    acc, gl_loss = evaluate(flat_mod.unpack(spec, params),
-                                            eval_x, eval_y)
+                valid = None
+                if robust_active:
+                    # the flush's validity mask: undelivered (dropout) slots
+                    # plus non-finite rows are screened out of aggregation
+                    valid = jnp.take(carry.pool_ok, flush) \
+                        & finite_rows(w_flush)
+                    params = robust_aggregate(robust, w_flush, carry.params,
+                                              valid, weights=lam)
+                    if is_flat:
+                        direction = update_global_direction_flat(
+                            carry.direction, carry.params, params, exp.lr,
+                            exp.momentum)
+                    else:
+                        direction = update_global_direction(
+                            carry.direction, carry.params, params, exp.lr,
+                            exp.momentum)
+                # an all-fresh buffer takes the sync engine's weights=None
+                # reduction (jnp.mean is NOT bitwise a uniform tensordot),
+                # so discount=1.0 + zero latency is bit-identical to sync
+                elif is_flat:
+                    params, direction = jax.lax.cond(
+                        all_fresh,
+                        lambda: server_update_flat(
+                            w_flush, carry.params, carry.direction, lr=exp.lr,
+                            gamma=exp.momentum, weights=None,
+                            use_kernel=use_kernel),
+                        lambda: server_update_flat(
+                            w_flush, carry.params, carry.direction, lr=exp.lr,
+                            gamma=exp.momentum, weights=lam / jnp.sum(lam),
+                            use_kernel=use_kernel))
                 else:
+                    params = jax.lax.cond(
+                        all_fresh,
+                        lambda: fedavg(w_flush, None),
+                        lambda: fedavg(w_flush, lam / jnp.sum(lam)))
                     direction = update_global_direction(
                         carry.direction, carry.params, params, exp.lr,
                         exp.momentum)
-                    acc, gl_loss = evaluate(params, eval_x, eval_y)
-            # an all-fresh buffer takes the sync engine's weights=None
-            # reduction (jnp.mean is NOT bitwise a uniform tensordot),
-            # so discount=1.0 + zero latency is bit-identical to sync
-            elif is_flat:
-                params, direction = jax.lax.cond(
-                    all_fresh,
-                    lambda: server_update_flat(
-                        w_flush, carry.params, carry.direction, lr=exp.lr,
-                        gamma=exp.momentum, weights=None,
-                        use_kernel=use_kernel),
-                    lambda: server_update_flat(
-                        w_flush, carry.params, carry.direction, lr=exp.lr,
-                        gamma=exp.momentum, weights=lam / jnp.sum(lam),
-                        use_kernel=use_kernel))
-                acc, gl_loss = evaluate(flat_mod.unpack(spec, params),
-                                        eval_x, eval_y)
-            else:
-                params = jax.lax.cond(
-                    all_fresh,
-                    lambda: fedavg(w_flush, None),
-                    lambda: fedavg(w_flush, lam / jnp.sum(lam)))
-                direction = update_global_direction(
-                    carry.direction, carry.params, params, exp.lr,
-                    exp.momentum)
-                acc, gl_loss = evaluate(params, eval_x, eval_y)
+
+            # ---- evaluation of the new global model ----
+            with jax.named_scope("fl_evaluate"):
+                acc, gl_loss = evaluate(
+                    flat_mod.unpack(spec, params) if is_flat else params,
+                    eval_x, eval_y)
 
             # ---- feedback: only FRESH updates may touch the bandit ----
             # (their momenta are projections against a direction the
             # server has since moved past — Eq. 3 scores of stale
             # updates are meaningless, so they are masked out exactly
             # like straggler-dropped clients in the sync backend)
-            if is_gpfl:
-                gp_scores = score_fn(d_flush, carry.direction)
-                vm = staleness == 0
-                if robust_active:
-                    # corrupted flushes must not write the bandit either
-                    vm = vm & valid & jnp.isfinite(gp_scores)
-                bandit, latest_gp = gpcb.observe(
-                    carry.bandit, carry.latest_gp, f_ids, gp_scores, acc,
-                    gl_loss, valid_mask=vm)
-            else:
-                bandit, latest_gp = carry.bandit, carry.latest_gp
+            with jax.named_scope("fl_observe"):
+                if is_gpfl:
+                    gp_scores = score_fn(d_flush, carry.direction)
+                    vm = staleness == 0
+                    if robust_active:
+                        # corrupted flushes must not write the bandit either
+                        vm = vm & valid & jnp.isfinite(gp_scores)
+                    bandit, latest_gp = gpcb.observe(
+                        carry.bandit, carry.latest_gp, f_ids, gp_scores, acc,
+                        gl_loss, valid_mask=vm)
+                else:
+                    bandit, latest_gp = carry.bandit, carry.latest_gp
 
-            seen = carry.seen.at[f_ids].set(True)
-            cov = jnp.mean(seen.astype(jnp.float32))
+                seen = carry.seen.at[f_ids].set(True)
+                cov = jnp.mean(seen.astype(jnp.float32))
 
-            # strike accounting happens at FLUSH time (when corruption
-            # becomes observable), before this event's dispatch selection
-            strikes = carry.strikes
-            if quarantine > 0:
-                offense = jnp.take(carry.pool_ok, flush) \
-                    & ~finite_rows(w_flush)
-                strikes = strikes.at[f_ids].add(offense.astype(jnp.int32))
+                # strike accounting happens at FLUSH time (when corruption
+                # becomes observable), before this event's dispatch selection
+                strikes = carry.strikes
+                if quarantine > 0:
+                    offense = jnp.take(carry.pool_ok, flush) \
+                        & ~finite_rows(w_flush)
+                    strikes = strikes.at[f_ids].add(offense.astype(jnp.int32))
 
             # ---- dispatch M replacements against the new model ----
-            params_in = flat_mod.unpack(spec, params) if is_flat \
-                else params
-            fc_cov, fc_prev = carry.fc_cov, carry.fc_prev
-            if quarantine > 0 and (is_gpfl or is_fedcor):
-                # same starvation guard as the sync body, at need = M
-                base = avail if has_avail else jnp.ones((N,), bool)
-                cand = base & (strikes < quarantine)
-                enough = jnp.sum(cand.astype(jnp.int32)) >= M
-                avail_arg = jnp.where(enough, cand, base)
-            # tier-1 pool for THIS dispatch, scored against the
-            # just-updated bandit/GP state (like the tier-2 dispatch)
-            pool_ids_r = pool_mask = sel_avail = None
-            if pooled:
-                u = gpcb.gpcb_values(bandit, E, exp.rho)
-                gp_term = gp_mod.normalize_gp(latest_gp)
-                pscores = gpcb.pool_scores(u, gp_term, carry.last_sel, t,
-                                           E, pjit, avail=avail_arg)
-                pool_ids_r = gpcb.pool_topk(pscores, P)
-                pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
-                base_m = avail_arg if avail_arg is not None \
-                    else jnp.ones((N,), bool)
-                sel_avail = compose_selection_mask(pool_mask, base_m, M)
-            if is_gpfl:
-                scores = gpcb.selection_scores(
-                    bandit, latest_gp, jitter, t, E, rho=exp.rho,
-                    use_ee=use_ee,
-                    avail=sel_avail if pooled else avail_arg)
-                n_ids = jnp.argsort(-scores)[:M]
-            elif is_random:
-                n_ids = jnp.take(pool_ids_r, sel_row[:M]) if pooled \
-                    else sel_row[:M]
-            elif is_powd:
-                cx, cy, csz = ClientStore.gather_tables(
-                    x_tab, y_tab, sz_tab, cand_row)
-                closs = loss_eval(params_in, cx, cy, csz)
+            with jax.named_scope("fl_select"):
+                key, kt = jax.random.split(carry.key)
+                params_in = flat_mod.unpack(spec, params) if is_flat \
+                    else params
+                fc_cov, fc_prev = carry.fc_cov, carry.fc_prev
+                if quarantine > 0 and (is_gpfl or is_fedcor):
+                    # same starvation guard as the sync body, at need = M
+                    base = avail if has_avail else jnp.ones((N,), bool)
+                    cand = base & (strikes < quarantine)
+                    enough = jnp.sum(cand.astype(jnp.int32)) >= M
+                    avail_arg = jnp.where(enough, cand, base)
+                # tier-1 pool for THIS dispatch, scored against the
+                # just-updated bandit/GP state (like the tier-2 dispatch)
+                pool_ids_r = pool_mask = sel_avail = None
                 if pooled:
-                    in_pool = jnp.take(pool_mask, cand_row)
-                    enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= M
-                    closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
-                                      closs)
-                n_ids = jnp.take(cand_row, jnp.argsort(-closs)[:M])
-            else:  # fedcor: probe the NEW model, select with the
-                # PRE-update covariance, then fold the probe in — the
-                # sync body's round-t ordering (t = e+1 >= 1, so the
-                # EMA update is unconditional here)
-                all_losses = loss_eval(params_in, x_tab, y_tab, sz_tab)
-                warm = (lambda: jnp.take(pool_ids_r, sel_row[:M])) \
-                    if pooled else (lambda: sel_row[:M])
-                n_ids = jax.lax.cond(
-                    t < W,
-                    warm,
-                    lambda: fedcor_greedy(
-                        carry.fc_cov, M,
-                        avail=sel_avail if pooled else avail_arg))
-                fc_cov = fedcor_cov_update(carry.fc_cov, carry.fc_prev,
-                                           all_losses, beta=_FEDCOR_BETA)
-                fc_prev = all_losses
-            n_ids = n_ids.astype(jnp.int32)
-
-            x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab,
-                                                    n_ids)
-            rngs = jax.random.split(kt, M)
-            w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
-            new_ok = jnp.ones((M,), bool)
-            if has_faults:
-                # this xs row is stream row t = e + 1 (the event scan
-                # slices row 0 off for the prefill), i.e. the dispatch
-                # slot's row — the sync body's round-t discipline
-                hit = jnp.take(flt, n_ids)
-                fkey = jax.random.fold_in(kt, 0x0F17)
-                w_i, d_i, new_ok = corrupt_cohort(
-                    faults, fkey, hit, w_i, d_i, params_in)
-            new_w = flat_mod.pack_stacked(spec, w_i) if is_flat else w_i
-            new_d = flat_mod.pack_stacked(spec, d_i) if is_flat else d_i
-
-            def cat(kept, new):
-                return jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b], axis=0), kept,
-                    new)
-
-            pool_w = cat(take(carry.pool_w, keep), new_w)
-            pool_d = cat(take(carry.pool_d, keep), new_d)
-            pool_ids = jnp.concatenate([jnp.take(carry.pool_ids, keep),
-                                        n_ids])
-            pool_ready = jnp.concatenate(
-                [jnp.take(carry.pool_ready, keep),
-                 clock + jnp.take(lat, n_ids)])
-            pool_ver = jnp.concatenate(
-                [jnp.take(carry.pool_ver, keep),
-                 jnp.full((M,), t, jnp.int32)])
-
-            if log_every:
-                fmt = (f"[{exp.name}/scan] event {{r}}/{E} acc={{a:.4f}} "
-                       "loss={l:.4f} cov={c:.2f}")
-                jax.lax.cond(
-                    (e + 1) % log_every == 0,
-                    lambda op: jax.debug.print(fmt, r=op[0] + 1, a=op[1],
-                                               l=op[2], c=op[3]),
-                    lambda op: None,
-                    (e, acc, gl_loss, cov))
-
-            out = {"ids": f_ids, "acc": acc, "loss": gl_loss,
-                   "coverage": cov, "sim_time": clock}
-            rep = dict(params=params, direction=direction, bandit=bandit,
-                       latest_gp=latest_gp, seen=seen, key=key,
-                       fc_cov=fc_cov, fc_prev=fc_prev, pool_w=pool_w,
-                       pool_d=pool_d, pool_ids=pool_ids,
-                       pool_ready=pool_ready, pool_ver=pool_ver,
-                       clock=clock)
-            if robust_active:
-                rep["pool_ok"] = jnp.concatenate(
-                    [jnp.take(carry.pool_ok, keep), new_ok])
-            if quarantine > 0:
-                rep["strikes"] = strikes
-            if pooled:
-                rep["last_sel"] = carry.last_sel.at[n_ids].set(
-                    jnp.asarray(t, jnp.float32))
-                out["pool"] = pool_ids_r
-            if counters:
-                # per-event metric counters (extra scan outs; never
-                # traced with the gate off — the off-mode parity
-                # contract).  The selection tally counts DISPATCHES
-                # (n_ids), matching the sync body's per-round cohort.
-                rep["sel_counts"] = carry.sel_counts.at[n_ids].add(1)
-                if robust_active:
-                    n_del = jnp.sum(valid.astype(jnp.float32))
-                else:
-                    n_del = jnp.asarray(float(M), jnp.float32)
+                    u = gpcb.gpcb_values(bandit, E, exp.rho)
+                    gp_term = gp_mod.normalize_gp(latest_gp)
+                    pscores = gpcb.pool_scores(u, gp_term, carry.last_sel, t,
+                                               E, pjit, avail=avail_arg)
+                    pool_ids_r = gpcb.pool_topk(pscores, P)
+                    pool_mask = jnp.zeros((N,), bool).at[pool_ids_r].set(True)
+                    base_m = avail_arg if avail_arg is not None \
+                        else jnp.ones((N,), bool)
+                    sel_avail = compose_selection_mask(pool_mask, base_m, M)
                 if is_gpfl:
-                    align = obs_metrics.alignment_cosine(
-                        gp_scores, obs_metrics.cohort_sq_norms(d_flush))
-                else:
-                    align = jnp.zeros((), jnp.float32)
-                out.update({
-                    "m_participants": jnp.asarray(float(M), jnp.float32),
-                    "m_delivered": n_del,
-                    "m_selection_entropy":
-                        obs_metrics.selection_entropy(rep["sel_counts"]),
-                    "m_gp_alignment": align,
-                    "m_screened": (M - n_del) if robust_active
-                        else jnp.zeros((), jnp.float32),
-                    "m_quarantined": jnp.sum(
-                        (strikes >= quarantine)
-                        .astype(jnp.float32)) if quarantine > 0
-                        else jnp.zeros((), jnp.float32),
-                    "m_pool_recall": jnp.mean(
-                        jnp.take(pool_mask, n_ids).astype(jnp.float32))
-                        if pooled else jnp.ones((), jnp.float32),
-                    "m_staleness_hist":
-                        obs_metrics.staleness_histogram(staleness),
-                })
+                    scores = gpcb.selection_scores(
+                        bandit, latest_gp, jitter, t, E, rho=exp.rho,
+                        use_ee=use_ee,
+                        avail=sel_avail if pooled else avail_arg)
+                    n_ids = jnp.argsort(-scores)[:M]
+                elif is_random:
+                    n_ids = jnp.take(pool_ids_r, sel_row[:M]) if pooled \
+                        else sel_row[:M]
+                elif is_powd:
+                    cx, cy, csz = ClientStore.gather_tables(
+                        x_tab, y_tab, sz_tab, cand_row)
+                    closs = loss_eval(params_in, cx, cy, csz)
+                    if pooled:
+                        in_pool = jnp.take(pool_mask, cand_row)
+                        enough_p = jnp.sum(in_pool.astype(jnp.int32)) >= M
+                        closs = jnp.where(enough_p & ~in_pool, -jnp.inf,
+                                          closs)
+                    n_ids = jnp.take(cand_row, jnp.argsort(-closs)[:M])
+                else:  # fedcor: probe the NEW model, select with the
+                    # PRE-update covariance, then fold the probe in — the
+                    # sync body's round-t ordering (t = e+1 >= 1, so the
+                    # EMA update is unconditional here)
+                    all_losses = loss_eval(params_in, x_tab, y_tab, sz_tab)
+                    warm = (lambda: jnp.take(pool_ids_r, sel_row[:M])) \
+                        if pooled else (lambda: sel_row[:M])
+                    n_ids = jax.lax.cond(
+                        t < W,
+                        warm,
+                        lambda: fedcor_greedy(
+                            carry.fc_cov, M,
+                            avail=sel_avail if pooled else avail_arg))
+                    fc_cov = fedcor_cov_update(carry.fc_cov, carry.fc_prev,
+                                               all_losses, beta=_FEDCOR_BETA)
+                    fc_prev = all_losses
+                n_ids = n_ids.astype(jnp.int32)
+
+            with jax.named_scope("fl_gather"):
+                x, y, sizes = ClientStore.gather_tables(x_tab, y_tab, sz_tab,
+                                                        n_ids)
+                rngs = jax.random.split(kt, M)
+            with jax.named_scope("fl_train"):
+                w_i, d_i, _ = trainer(params_in, x, y, sizes, rngs)
+                new_ok = jnp.ones((M,), bool)
+                if has_faults:
+                    # this xs row is stream row t = e + 1 (the event scan
+                    # slices row 0 off for the prefill), i.e. the dispatch
+                    # slot's row — the sync body's round-t discipline
+                    hit = jnp.take(flt, n_ids)
+                    fkey = jax.random.fold_in(kt, 0x0F17)
+                    w_i, d_i, new_ok = corrupt_cohort(
+                        faults, fkey, hit, w_i, d_i, params_in)
+                new_w = flat_mod.pack_stacked(spec, w_i) if is_flat else w_i
+                new_d = flat_mod.pack_stacked(spec, d_i) if is_flat else d_i
+
+                def cat(kept, new):
+                    return jax.tree.map(
+                        lambda a, b: jnp.concatenate([a, b], axis=0), kept,
+                        new)
+
+                pool_w = cat(take(carry.pool_w, keep), new_w)
+                pool_d = cat(take(carry.pool_d, keep), new_d)
+                pool_ids = jnp.concatenate([jnp.take(carry.pool_ids, keep),
+                                            n_ids])
+                pool_ready = jnp.concatenate(
+                    [jnp.take(carry.pool_ready, keep),
+                     clock + jnp.take(lat, n_ids)])
+                pool_ver = jnp.concatenate(
+                    [jnp.take(carry.pool_ver, keep),
+                     jnp.full((M,), t, jnp.int32)])
+
+            with jax.named_scope("fl_observe"):
+                if log_every:
+                    fmt = (f"[{exp.name}/scan] event {{r}}/{E} acc={{a:.4f}} "
+                           "loss={l:.4f} cov={c:.2f}")
+                    jax.lax.cond(
+                        (e + 1) % log_every == 0,
+                        lambda op: jax.debug.print(fmt, r=op[0] + 1, a=op[1],
+                                                   l=op[2], c=op[3]),
+                        lambda op: None,
+                        (e, acc, gl_loss, cov))
+
+                out = {"ids": f_ids, "acc": acc, "loss": gl_loss,
+                       "coverage": cov, "sim_time": clock}
+                rep = dict(params=params, direction=direction, bandit=bandit,
+                           latest_gp=latest_gp, seen=seen, key=key,
+                           fc_cov=fc_cov, fc_prev=fc_prev, pool_w=pool_w,
+                           pool_d=pool_d, pool_ids=pool_ids,
+                           pool_ready=pool_ready, pool_ver=pool_ver,
+                           clock=clock)
+                if robust_active:
+                    rep["pool_ok"] = jnp.concatenate(
+                        [jnp.take(carry.pool_ok, keep), new_ok])
+                if quarantine > 0:
+                    rep["strikes"] = strikes
+                if pooled:
+                    rep["last_sel"] = carry.last_sel.at[n_ids].set(
+                        jnp.asarray(t, jnp.float32))
+                    out["pool"] = pool_ids_r
+                if counters:
+                    # per-event metric counters (extra scan outs; never
+                    # traced with the gate off — the off-mode parity
+                    # contract).  The selection tally counts DISPATCHES
+                    # (n_ids), matching the sync body's per-round cohort.
+                    rep["sel_counts"] = carry.sel_counts.at[n_ids].add(1)
+                    if robust_active:
+                        n_del = jnp.sum(valid.astype(jnp.float32))
+                    else:
+                        n_del = jnp.asarray(float(M), jnp.float32)
+                    if is_gpfl:
+                        align = obs_metrics.alignment_cosine(
+                            gp_scores, obs_metrics.cohort_sq_norms(d_flush))
+                    else:
+                        align = jnp.zeros((), jnp.float32)
+                    out.update({
+                        "m_participants": jnp.asarray(float(M), jnp.float32),
+                        "m_delivered": n_del,
+                        "m_selection_entropy":
+                            obs_metrics.selection_entropy(rep["sel_counts"]),
+                        "m_gp_alignment": align,
+                        "m_screened": (M - n_del) if robust_active
+                            else jnp.zeros((), jnp.float32),
+                        "m_quarantined": jnp.sum(
+                            (strikes >= quarantine)
+                            .astype(jnp.float32)) if quarantine > 0
+                            else jnp.zeros((), jnp.float32),
+                        "m_pool_recall": jnp.mean(
+                            jnp.take(pool_mask, n_ids).astype(jnp.float32))
+                            if pooled else jnp.ones((), jnp.float32),
+                        "m_staleness_hist":
+                            obs_metrics.staleness_histogram(staleness),
+                    })
             return carry._replace(**rep), out
 
         return body
@@ -1677,11 +1704,10 @@ class ScanEngine:
         return self._run_chunked(resume=resume, until_round=until_round)
 
     def _span(self, name: str, **args):
-        """A tracer span under ``telemetry="trace"``, else a no-op
-        context — so dispatch sites wrap unconditionally."""
-        if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
+        """A program span (``repro.obs.trace``): the profiler annotation
+        in every telemetry mode, plus the Chrome event under
+        ``telemetry="trace"``."""
+        return (self.tracer or _NULL_TRACER).span(name, **args)
 
     def _scan_args(self) -> tuple:
         """The full-run scan's arguments.  params/direction are donated
@@ -1704,15 +1730,17 @@ class ScanEngine:
         """The snapshot-free fast path: one dispatch for the whole run
         (all T rounds, or — buffered — prefill + all E events)."""
         t0 = time.perf_counter()
+        with self._span("copy_inputs"):
+            args = self._scan_args()
         with self._span("scan_dispatch", rounds=int(self.events)):
-            carry, out = jax.block_until_ready(
-                self._compiled()(*self._scan_args()))
+            carry, out = jax.block_until_ready(self._compiled()(*args))
         scan_wall = time.perf_counter() - t0
         self.final_carry = carry
-
-        return self._result(
-            {k: np.asarray(v) for k, v in out.items()},
-            wall=scan_wall, rounds_timed=self.events)
+        with self._span("fetch_outputs"):
+            outs = {k: np.asarray(v) for k, v in out.items()}
+        with self._span("build_result"):
+            return self._result(outs, wall=scan_wall,
+                                rounds_timed=self.events)
 
     def _run_chunked(self, *, resume: bool,
                      until_round: Optional[int]) -> Optional[RunResult]:

@@ -88,6 +88,7 @@ def fedavg_momentum_pallas(w_matrix, w_prev, direction, weights, *, lr,
             jax.ShapeDtypeStruct((1, Dp), jnp.float32),
         ],
         interpret=interpret,
+        name="fedavg_momentum",
     )(inv_lr, w_matrix, w_prev.reshape(1, Dp), direction.reshape(1, Dp), wt2)
     p_new, d_new = p_new[0], d_new[0]
     if pad:

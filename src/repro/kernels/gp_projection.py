@@ -99,6 +99,7 @@ def gp_projection_pallas(grads, direction, *, block_d: int = DEFAULT_BLOCK_D,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gp_projection",
     )(grads, d2)
     return dots[:, 0] / jnp.maximum(jnp.sqrt(nsq[0, 0]), 1e-12)
 

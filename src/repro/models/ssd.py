@@ -103,34 +103,37 @@ def ssd_chunked(xh, dt, a_log, bmat, cmat, chunk: int, h0=None):
     # overflows to inf there and where(causal, exp(seg), 0) would feed
     # inf·0 = NaN into the backward pass (bites at chunk ≥ 64 with the
     # a_log="ones" init); exp(-inf) = 0 keeps both directions finite.
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]    # (B,nc,q,k,H)
-    idx = jnp.arange(chunk)
-    causal = idx[:, None] >= idx[None, :]
-    L = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
-    qk = jnp.einsum("bcqn,bckn->bcqk", cc, bc)             # (B,nc,q,k)
-    y_intra = jnp.einsum("bcqk,bcqkh,bckhp->bcqhp", qk, L, xc)
+    with jax.named_scope("ssd_intra_chunk"):
+        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,q,k,H)
+        idx = jnp.arange(chunk)
+        causal = idx[:, None] >= idx[None, :]
+        L = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
+        qk = jnp.einsum("bcqn,bckn->bcqk", cc, bc)           # (B,nc,q,k)
+        y_intra = jnp.einsum("bcqk,bcqkh,bckhp->bcqhp", qk, L, xc)
 
-    # chunk summary states: S_c = Σ_k exp(cum_end - cum_k) B_k x_kᵀ
-    decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)        # (B,nc,chunk,H)
-    sstates = jnp.einsum("bckn,bckh,bckhp->bchpn", bc, decay_to_end, xc)
+        # chunk summary states: S_c = Σ_k exp(cum_end - cum_k) B_k x_kᵀ
+        decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)      # (B,nc,chunk,H)
+        sstates = jnp.einsum("bckn,bckh,bckhp->bchpn", bc, decay_to_end, xc)
 
     # inter-chunk recurrence over chunk states
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                # (B,nc,H)
+    with jax.named_scope("ssd_inter_chunk"):
+        chunk_decay = jnp.exp(cum[:, :, -1, :])              # (B,nc,H)
 
-    def body(h, xs):
-        s_c, dec = xs                                      # (B,H,P,N), (B,H)
-        h_new = h * dec[:, :, None, None] + s_c
-        return h_new, h                                    # emit state *before* chunk
+        def body(h, xs):
+            s_c, dec = xs                                    # (B,H,P,N), (B,H)
+            h_new = h * dec[:, :, None, None] + s_c
+            return h_new, h                        # emit state *before* chunk
 
-    if h0 is None:
-        h0 = jnp.zeros((B, H, Pd, N), jnp.float32)
-    h_final, h_prev = jax.lax.scan(
-        body, h0,
-        (sstates.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
-    h_prev = h_prev.transpose(1, 0, 2, 3, 4)               # (B,nc,H,P,N)
+        if h0 is None:
+            h0 = jnp.zeros((B, H, Pd, N), jnp.float32)
+        h_final, h_prev = jax.lax.scan(
+            body, h0,
+            (sstates.transpose(1, 0, 2, 3, 4),
+             chunk_decay.transpose(1, 0, 2)))
+        h_prev = h_prev.transpose(1, 0, 2, 3, 4)             # (B,nc,H,P,N)
 
-    y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp",
-                         cc, jnp.exp(cum), h_prev)
+        y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp",
+                             cc, jnp.exp(cum), h_prev)
     y = (y_intra + y_inter).reshape(B, S, H, Pd)
     return y, h_final
 

@@ -11,8 +11,17 @@ the capability registry (see ``repro.api.capabilities``):
   buffered event body (:mod:`repro.obs.metrics`), then finalised host-side
   with exact byte accounting (:mod:`repro.obs.cost`).
 * ``"trace"`` — counters **plus** a host-side span tracer that emits
-  Chrome/Perfetto trace-event JSON around jit dispatches, ``device_put``
-  slabs and snapshot writes (:mod:`repro.obs.trace`).
+  Chrome/Perfetto trace-event JSON around input copies, jit dispatches,
+  output fetches, ``device_put`` slabs and snapshot writes
+  (:mod:`repro.obs.trace`).
+
+In every mode those spans are ``jax.profiler.TraceAnnotation``s, so a
+``jax.profiler`` capture places them on the device trace's clock; and the
+traced programs carry ``jax.named_scope`` stage names (``fl_select`` …
+``fl_observe`` in the round engine, ``gpfl_score``/``gpfl_gate``/
+``step_grad``/``step_update`` in the gated step, ``ssd_intra_chunk``/
+``ssd_inter_chunk`` in the SSD scan) in their ops' metadata, which the
+device trace reports per op.  Both are metadata: the numbers are the same.
 
 Per-cell metric rows are persisted to a JSONL sink keyed by
 ``cell_fingerprint`` and joined back against the run journal
@@ -33,7 +42,8 @@ from repro.obs.metrics import (
     MetricBuffer,
     finalize_metrics,
 )
-from repro.obs.trace import NullTracer, SpanTracer, validate_trace
+from repro.obs.trace import (SPAN_NAMES, NullTracer, SpanTracer,
+                             validate_trace)
 
 __all__ = [
     "CostModel",
@@ -42,6 +52,7 @@ __all__ = [
     "MetricBuffer",
     "MetricSink",
     "NullTracer",
+    "SPAN_NAMES",
     "STALENESS_BINS",
     "SpanTracer",
     "bytes_curve",

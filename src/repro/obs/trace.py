@@ -1,15 +1,20 @@
-"""Host-side span tracer → Chrome/Perfetto trace-event JSON.
+"""Program spans: one API, on the profiler's clock and in Chrome JSON.
 
-The ``telemetry="trace"`` path wraps the engine's host-visible boundaries —
-jit dispatches, ``device_put`` slab uploads in the streamed pre-selection
-path, snapshot writes — in :meth:`SpanTracer.span` blocks.  Spans are
-recorded as Chrome trace-event "X" (complete) events, so the saved JSON
-loads directly in ``chrome://tracing`` / Perfetto.
+The engines wrap their host-visible boundaries — input copies, jit
+dispatches, output fetches, ``device_put`` slab uploads in the streamed
+pre-selection path, snapshot writes — in ``tracer.span(name)`` blocks.
+Every span, whatever the telemetry mode, opens a
+``jax.profiler.TraceAnnotation`` of the same name: it costs next to
+nothing while no profiler runs, and inside a ``jax.profiler`` capture it
+lands on the host plane on the same clock as the device ops, so a trace
+can say what the host was doing in each idle gap of the device.
 
-Scope note: spans deliberately measure *host* time (dispatch + blocking
-waits), not device time.  For device-side profiles the bench lane can opt
-into :func:`profiler_capture`, a thin wrapper over ``jax.profiler``'s
-programmatic capture API.
+Under ``telemetry="trace"`` :class:`SpanTracer` also records each span as
+a Chrome trace-event "X" (complete) event, so the saved JSON loads
+directly in ``chrome://tracing`` / Perfetto.  Keyword args go only into
+that event, never into the annotation's name.
+
+Every span name the program opens is listed in :data:`SPAN_NAMES`.
 """
 from __future__ import annotations
 
@@ -18,10 +23,23 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import List
+
+import jax
 
 #: Chrome trace-event phase codes this module emits / validates.
-TRACE_PHASES = ("X", "i", "M")
+TRACE_PHASES = ("X", "M")
+
+#: Every span name the program opens (``ScanEngine``'s single, chunked
+#: and buffered dispatch paths; the streamed pre-selection runner).
+SPAN_NAMES = frozenset({
+    # ScanEngine._run_single
+    "copy_inputs", "scan_dispatch", "fetch_outputs", "build_result",
+    # ScanEngine._run_chunked
+    "prefill_dispatch", "chunk_dispatch", "snapshot_write",
+    # repro.fl.preselect.run_pooled_stream
+    "tier1_pool", "device_put_pool", "round_dispatch",
+})
 
 
 class SpanTracer:
@@ -49,14 +67,16 @@ class SpanTracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Context manager recording one complete ("X") event around a block.
+        """A profiler annotation plus one complete ("X") event around a
+        block.
 
         Keyword ``args`` land in the event's ``args`` payload (must be
         JSON-serialisable; keep them small — round indices, byte counts).
         """
         t0 = self._now_us()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name):
+                yield self
         finally:
             t1 = self._now_us()
             ev = {
@@ -67,17 +87,6 @@ class SpanTracer:
                 ev["args"] = args
             with self._lock:
                 self.events.append(ev)
-
-    def instant(self, name: str, **args) -> None:
-        """Record a zero-duration instant ("i") event (e.g. a retry mark)."""
-        ev = {
-            "name": name, "ph": "i", "ts": self._now_us(), "s": "t",
-            "pid": self._pid, "tid": threading.get_ident() & 0xFFFF,
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self.events.append(ev)
 
     def to_dict(self) -> dict:
         """The trace as a Chrome trace-event JSON object."""
@@ -95,19 +104,14 @@ class SpanTracer:
 
 
 class NullTracer:
-    """No-op stand-in so call sites can write ``tracer.span(...)`` unconditionally."""
+    """The tracer outside ``telemetry="trace"``: the profiler annotation
+    alone, so call sites write ``tracer.span(...)`` unconditionally."""
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Yield immediately; records nothing."""
-        yield self
-
-    def instant(self, name: str, **args) -> None:
-        """Records nothing."""
-
-    def to_dict(self) -> dict:
-        """An empty (but schema-valid) trace object."""
-        return {"traceEvents": [], "displayTimeUnit": "ms"}
+        """The profiler annotation ``name``; ``args`` are dropped."""
+        with jax.profiler.TraceAnnotation(name):
+            yield self
 
 
 def validate_trace(obj: dict) -> List[str]:
@@ -142,36 +146,11 @@ def validate_trace(obj: dict) -> List[str]:
     return problems
 
 
-@contextlib.contextmanager
-def profiler_capture(logdir: Optional[str]):
-    """Opt-in ``jax.profiler`` programmatic capture around a block.
-
-    ``logdir=None`` (the default everywhere outside the bench lane) is a
-    no-op.  A trace that cannot start or stop raises: a run asked to be
-    profiled does not silently come back without its trace.
-    """
-    if not logdir:
-        yield
-        return
-    import jax
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def tracer_for(telemetry: str):
-    """The tracer matching a telemetry mode: real for "trace", null otherwise."""
-    return SpanTracer() if telemetry == "trace" else NullTracer()
-
-
 #: Re-exported for callers that only need type names.
 __all__ = [
     "NullTracer",
+    "SPAN_NAMES",
     "SpanTracer",
     "TRACE_PHASES",
-    "profiler_capture",
-    "tracer_for",
     "validate_trace",
 ]
