@@ -89,15 +89,18 @@ def fl_phase(exp) -> None:
     import jax.numpy as jnp
     from repro.api import ExecutionSpec
     from repro.core import flat
-    from repro.fl.simulation import resolve_gp_impl
+    from repro.fl.client import minibatch_path
+    from repro.fl.simulation import _build_data, resolve_gp_impl
     from repro.kernels.interpret import resolve_interpret
     from repro.kernels.ops import gp_projection
     from repro.models import small
 
     impl = resolve_gp_impl("auto", False)
+    cap = _build_data(exp, exp.seed)[0].capacity
     print(f"[fl] {exp.name}: N={exp.n_clients} K={exp.clients_per_round} "
           f"local_iters={exp.local_iters} batch={exp.local_batch_size} "
           f"rounds={exp.rounds} gp_impl={impl} "
+          f"minibatch_path={minibatch_path(cap)} (cap={cap}) "
           f"pallas_interpret={resolve_interpret(None)}", flush=True)
     if impl == "kernel":
         spec = flat.make_flat_spec(small.init(jax.random.key(0), exp.model))

@@ -9,6 +9,10 @@ Returns per client:
   * final local params  w_i^t
   * final momentum      d_i^t   — the "momentum-based gradient" GPFL projects
   * mean local loss (diagnostics / Pow-d probes)
+
+A client's minibatches for the whole round are drawn and built once, before
+the local scan, so no row gather runs inside the local-iteration loop (see
+``minibatch_rows``).
 """
 from __future__ import annotations
 
@@ -20,6 +24,90 @@ import jax.numpy as jnp
 
 from repro.configs.paper import FLExperimentConfig, SmallModelConfig
 from repro.models import small
+
+
+# The tallest client table whose minibatch rows are built by the one-hot
+# contraction on a TPU.  The contraction's cost grows with the table height
+# and the gather's does not; on a v5e the FEMNIST trainer is the faster with
+# it at 226 and 512 rows and the slower at 1,024 (PERF.md, C* micro-run).
+CONTRACT_MAX_ROWS = 512
+
+
+def minibatch_path(cap: int, backend: str | None = None) -> str:
+    """How a client table of ``cap`` rows is turned into minibatch rows:
+    ``"contract"`` (the exact one-hot contraction on the MXU) on a TPU up to
+    ``CONTRACT_MAX_ROWS`` rows, else ``"take"`` (one row gather).  Both give
+    the same rows bit for bit; ``backend`` defaults to JAX's default."""
+    backend = jax.default_backend() if backend is None else backend
+    return "contract" if backend == "tpu" and cap <= CONTRACT_MAX_ROWS \
+        else "take"
+
+
+def _bf16_head(v):
+    """``v`` (float32) cut to its leading 8 significant bits, a value that
+    bfloat16 holds exactly.  Cut by masking the bits: a TPU may keep a
+    float32 → bfloat16 → float32 round trip in float32 (excess precision),
+    which would leave nothing for the lower parts."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def split_bf16(x):
+    """``x`` (float32) as three bfloat16 parts ``hi``, ``mid``, ``lo`` with
+    ``(hi + mid) + lo == x`` exactly in float32: ``hi`` holds the leading 8
+    bits of the 24-bit significand, ``mid`` the next 8 of the remainder and
+    ``lo`` the rest, each exact in bfloat16, each difference exact in
+    float32.  Exact for zero and for magnitudes from 2**-103 (below, the
+    lower parts fall under float32's normal range and may be flushed to
+    zero) up to float32's largest."""
+    hi = _bf16_head(x)
+    r = x - hi
+    mid = _bf16_head(r)
+    lo = r - mid
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
+
+
+def contract_rows(x, idx):
+    """``x[idx]`` for a float32 ``x`` of shape ``(cap, *feat)`` and int
+    ``idx`` of any shape, as a one-hot contraction on the matrix unit.
+
+    Each bf16 part of ``x`` (``split_bf16``) is contracted with the
+    ``(idx.size, cap)`` one-hot selection in one single-pass bf16 dot that
+    accumulates in float32.  Every output element is one nonzero product a
+    part, so each dot returns its part exactly and the float32 sum rebuilds
+    ``x[idx]`` bit for bit over ``split_bf16``'s range (a ``-0.0`` comes
+    back as ``+0.0``; a non-finite value in the table spoils its column)."""
+    cap = x.shape[0]
+    flat = x.reshape(cap, -1)
+    onehot = (idx.reshape(-1, 1) == jnp.arange(cap, dtype=idx.dtype)
+              ).astype(jnp.bfloat16)
+
+    def dot(part):
+        return jnp.dot(onehot, part, preferred_element_type=jnp.float32)
+
+    hi, mid, lo = split_bf16(flat)
+    rows = (dot(hi) + dot(mid)) + dot(lo)
+    return rows.reshape(idx.shape + x.shape[1:])
+
+
+def select_labels(y, idx):
+    """``y[idx]`` for an integer ``y`` of shape ``(cap,)``, as a sum over
+    the one-hot selection: one nonzero term a row, so exact for any value.
+    A few thousand single-element gathers cost the TPU tens of µs; this
+    costs it one pass over ``idx.size × cap`` elements."""
+    hit = idx[..., None] == jnp.arange(y.shape[0], dtype=idx.dtype)
+    return jnp.sum(jnp.where(hit, y, jnp.zeros((), y.dtype)), axis=-1)
+
+
+def minibatch_rows(x, y, idx):
+    """The minibatches ``(x[idx], y[idx])`` of one client table: ``x`` is
+    ``(cap, *feat)``, ``y`` ``(cap,)``, ``idx`` ``(iters, B)``.  The path
+    follows ``minibatch_path`` (float32 tables only take the contraction);
+    the rows and labels are the same on either."""
+    if x.dtype == jnp.float32 and minibatch_path(x.shape[0]) == "contract":
+        return contract_rows(x, idx), select_labels(y, idx)
+    xb = jnp.take(x, idx.reshape(-1), axis=0).reshape(idx.shape + x.shape[1:])
+    return xb, jnp.take(y, idx, axis=0)
 
 
 def make_cohort_trainer(exp: FLExperimentConfig) -> Callable:
@@ -35,11 +123,15 @@ def make_cohort_trainer(exp: FLExperimentConfig) -> Callable:
     cfg = exp.model
 
     def one_client(params0, x, y, size, rng):
-        def step(carry, rng_i):
+        # step i's draw from its own key, as a per-step draw would make it
+        rngs = jax.random.split(rng, exp.local_iters)
+        idx = jax.vmap(lambda r: jax.random.randint(
+            r, (exp.local_batch_size,), 0, jnp.maximum(size, 1)))(rngs)
+        with jax.named_scope("fl_minibatch"):
+            xb, yb = minibatch_rows(x, y, idx)
+
+        def step(carry, batch):
             params, d = carry
-            idx = jax.random.randint(rng_i, (exp.local_batch_size,), 0,
-                                     jnp.maximum(size, 1))
-            batch = {"x": x[idx], "y": y[idx]}
             loss, grads = jax.value_and_grad(small.loss_fn)(params, batch, cfg)
 
             def upd(p, g, m):
@@ -55,8 +147,8 @@ def make_cohort_trainer(exp: FLExperimentConfig) -> Callable:
             return (params, d), loss
 
         d0 = jax.tree.map(jnp.zeros_like, params0)
-        rngs = jax.random.split(rng, exp.local_iters)
-        (params, d), losses = jax.lax.scan(step, (params0, d0), rngs)
+        (params, d), losses = jax.lax.scan(step, (params0, d0),
+                                           {"x": xb, "y": yb})
         return params, d, jnp.mean(losses)
 
     cohort = jax.vmap(one_client, in_axes=(None, 0, 0, 0, 0))

@@ -171,7 +171,8 @@ from repro.core.selector import (fedcor_cov_update, fedcor_greedy,
 from repro.data import ClientStore
 from repro.dist.sharding import (cohort_axis_rules, cohort_specs,
                                  population_axis_rules)
-from repro.fl.client import make_cohort_loss_eval, make_cohort_trainer
+from repro.fl.client import (make_cohort_loss_eval, make_cohort_trainer,
+                              minibatch_path)
 from repro.fl.faults import (FaultConfig, corrupt_cohort, fault_stream,
                              make_faults)
 from repro.fl.latency import (AggregationConfig, ScenarioConfig,
@@ -455,11 +456,19 @@ class ScanEngine:
             # actually host-resident.
             self._stream_data = data if (data is not None and getattr(
                 data[0], "host_tables", False)) else None
+            # how local training builds its minibatch rows (None until the
+            # streamed loop builds its tables)
+            self.minibatch_path = minibatch_path(
+                self._stream_data[0].capacity) if self._stream_data else None
             self._defer_init = bool(defer_init)
             self._jit = {}
             return
         self.store, self.eval_x, self.eval_y = data if data is not None \
             else _build_data(exp, exp.seed)
+        # how local training builds its minibatch rows: by the table height
+        # and the backend alone, and the same rows either way, so it stays
+        # out of ``fingerprint()``
+        self.minibatch_path = minibatch_path(self.store.capacity)
         # the tier-1 pool size, clamped to the population (the registry
         # already guarantees pool_size >= K)
         self.pool_size = min(int(self.pre.pool_size),

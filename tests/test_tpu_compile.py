@@ -12,14 +12,16 @@ worker that runs these tests, and never while a module is imported.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.paper import CIFAR10_CNN, FEMNIST_MLP
+from repro.configs.paper import CIFAR10_CNN, FEMNIST_MLP, femnist_experiment
 from repro.core import flat
+from repro.fl import client
 from repro.kernels.fedavg_momentum import fedavg_momentum_pallas
 from repro.kernels.gp_projection import (gp_projection_pallas,
                                          gp_projection_softmax_pallas)
@@ -91,3 +93,88 @@ def test_fl_kernel_compiles_for_v5e(one_chip, model, k, kernel):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _computations(hlo: str) -> dict:
+    """``{name: [op lines]}`` of an HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _reachable(comps: dict, root: str) -> list:
+    """The op lines of ``root`` and of every computation it calls."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            lines.append(line)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               line)
+            for group in re.findall(r"(?:branch|called)_computations=\{([^}]*)\}",
+                                    line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return lines
+
+
+def _table_gathers(lines, width: int) -> list:
+    """The gathers among ``lines`` that take ``width``-wide rows."""
+    return [ln for ln in lines if " gather(" in ln and re.search(
+        r"slice_sizes=\{[^}]*\b%d\}" % width, ln)]
+
+
+def _loop_bodies(comps: dict) -> list:
+    return [m.group(1) for lines in comps.values() for ln in lines
+            for m in [re.search(r" while\(.*body=%([\w.\-]+)", ln)] if m]
+
+
+@pytest.mark.parametrize("cap", [226, client.CONTRACT_MAX_ROWS + 1],
+                         ids=["femnist", "taller_than_cstar"])
+def test_cohort_trainer_rows_for_v5e(one_chip, monkeypatch, cap):
+    """The cohort trainer at the FEMNIST MLP's shapes (K=5, batch 64, 20
+    local iterations): up to ``CONTRACT_MAX_ROWS`` table rows its minibatch
+    rows come from the one-hot contraction, a taller table takes one row
+    gather, and either way no gather of the table is left in the local
+    iterations' loop."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    exp = femnist_experiment()
+    K, width = exp.clients_per_round, 784
+    params = jax.eval_shape(lambda: small.init(jax.random.key(0), exp.model))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), K))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    args = [jax.tree.map(on_chip, params),
+            on_chip(jax.ShapeDtypeStruct((K, cap, width), jnp.float32)),
+            on_chip(jax.ShapeDtypeStruct((K, cap), jnp.int32)),
+            on_chip(jax.ShapeDtypeStruct((K,), jnp.int32)), on_chip(keys)]
+    hlo = client.make_cohort_trainer(exp).lower(*args).compile().as_text()
+    comps = _computations(hlo)
+    bodies = _loop_bodies(comps)
+    assert bodies, "the local iterations compiled without a loop"
+    for body in bodies:
+        assert not _table_gathers(_reachable(comps, body), width)
+    path = client.minibatch_path(cap)
+    minibatch = [ln for ln in hlo.splitlines() if "fl_minibatch" in ln]
+    gathers = _table_gathers(minibatch, width)
+    if cap <= client.CONTRACT_MAX_ROWS:
+        assert path == "contract"
+        assert not [ln for ln in minibatch if " gather(" in ln]
+        assert any(re.search(r"= f32\[%d,%d,%d\]\S* convolution" % (
+            K, exp.local_iters * exp.local_batch_size, width), ln)
+            for ln in minibatch)
+    else:
+        assert path == "take"
+        assert gathers
